@@ -48,6 +48,9 @@ WAL is compacted (records at or below the snapshot LSN dropped).
 below the applied LSN, so replaying twice equals replaying once), then a
 reconciliation pass of the storage blobs against the recovered manifest:
 
+  * an undo copy (core/storage.py) of a blob the op replaced or deleted
+    before its WAL record landed → ROLL BACK: the copy goes back in place
+    when it, and not the live blob, carries the manifest's CRC;
   * a blob for a cluster the manifest doesn't claim → ORPHAN GC (a put
     that landed before its WAL record did; deleting it lands the index
     exactly on the pre-op state);
@@ -57,10 +60,14 @@ reconciliation pass of the storage blobs against the recovered manifest:
 
 THE ATOMICITY CONTRACT.  With a :class:`~repro.core.faults.CrashInjector`
 cutting the process at any durability write boundary
-(:data:`~repro.core.faults.CRASH_POINTS`), recovery always lands
-bit-identical to the pre-op or the post-op index — never a torn hybrid.
-The mechanism: blobs are written before their WAL record, so a lost
-record orphans (GC → pre-op) and a torn record truncates (→ pre-op),
+(:data:`~repro.core.faults.CRASH_POINTS`), recovery always lands on the
+pre-op or the post-op index (membership, stamps and search ids exactly,
+scores to f32 rounding) — never a torn hybrid.  The mechanism: blobs are
+written before their WAL record, and a blob an op replaces or deletes
+keeps an undo copy until the record lands, so a lost record orphans new
+blobs (GC → pre-op) and rolls replaced ones back (→ pre-op) —
+re-embedding them instead would read chunk texts that may already be
+newer than the pre-op index — and a torn record truncates (→ pre-op),
 while a landed record pins the exact post-op state including each stored
 blob's CRC (mismatch → heal → post-op content).  The property tests
 (tests/test_durability_properties.py) fuzz this over random mutation
@@ -623,6 +630,7 @@ class RecoveryReport:
     replayed_records: int = 0
     torn_bytes: int = 0          # bytes cut off the WAL's torn tail
     orphans_gc: int = 0          # blobs the manifest didn't claim, deleted
+    rolled_back: int = 0         # pre-op blobs put back from undo copies
     healed: int = 0              # manifest-claimed blobs regenerated
     requeued_ops: int = 0        # split/merge hygiene re-derived post-replay
     edge_s: float = 0.0
@@ -708,6 +716,9 @@ def recover_index(index, dur: Durability, *,
             1 for rec in records if int(rec["lsn"]) > snap_lsn)
         index.attach_durability(dur, checkpoint=False)
         # ---- blob reconciliation against the recovered manifest ----
+        for cid in index.storage.undo_keys():
+            rep.rolled_back += index.storage.resolve_undo(
+                cid, manifest.get(cid))
         present = set(index.storage.keys())
         claimed = set()
         for cid, cl in enumerate(index.clusters):

@@ -321,6 +321,7 @@ class EdgeRAGIndex:
         self.durability = durability
         self._dirty.clear()
         self._gone.clear()
+        self.storage.track_undo()
         durability.manifest = {
             cid: self.storage.payload_crc(cid)
             for cid, cl in enumerate(self.clusters)
@@ -334,18 +335,23 @@ class EdgeRAGIndex:
         absolute post-op state of every touched cluster; returns modeled
         fsync edge seconds (0 with no handle attached).  Blobs are always
         written BEFORE this runs, so a crash between blob and record
-        orphans the blob (recovery GCs it back to pre-op) rather than ever
-        leaving a hybrid."""
+        orphans a new blob (recovery GCs it back to pre-op) or leaves the
+        undo copy of a replaced one (recovery puts it back) rather than
+        ever leaving a hybrid.  Once the record has landed the op's undo
+        copies are dropped."""
         dirty, gone = self._dirty, self._gone
         if self.durability is None or not (dirty or gone):
             dirty.clear()
             gone.clear()
+            self.storage.discard_undo()
             return 0.0
         cids = sorted(c for c in dirty if c < len(self.clusters))
         removed = sorted(gone)
         dirty.clear()
         gone.clear()
-        return self.durability.log_mutation(self, op, cids, removed)
+        fsync_s = self.durability.log_mutation(self, op, cids, removed)
+        self.storage.discard_undo()
+        return fsync_s
 
     # ------------------------------------------------------------------
     # indexing (Fig. 8 + Alg. 1)

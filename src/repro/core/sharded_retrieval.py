@@ -20,11 +20,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.slab_topk.ops import ROW_PAD
 from repro.kernels.slab_topk.ref import NOT_PROBED
-from repro.models.distributed import shard_map   # jax 0.4/0.5 compat shim
 
 NEG_INF = -1e30
 
@@ -46,7 +46,9 @@ def sharded_topk_ip(embs, queries, k: int, mesh, axis: str = "data"
     def local_fn(emb_loc, q):
         shard = jax.lax.axis_index(axis)
         s_rows = emb_loc.shape[0]
-        scores = q.astype(jnp.float32) @ emb_loc.astype(jnp.float32).T
+        scores = jnp.matmul(q.astype(jnp.float32),
+                            emb_loc.astype(jnp.float32).T,
+                            precision=jax.lax.Precision.HIGHEST)
         base = shard * s_rows + jnp.arange(s_rows)
         scores = jnp.where((base < n)[None, :], scores, NEG_INF)
         kk = min(k, s_rows)
@@ -110,7 +112,9 @@ def sharded_slab_topk(emb, queries, virt, k: int, mesh, axis: str = "data",
         if luts is not None:
             scores = pq_adc_scores(emb_loc, extras[0].astype(jnp.float32))
         else:
-            scores = q.astype(jnp.float32) @ emb_loc.astype(jnp.float32).T
+            scores = jnp.matmul(q.astype(jnp.float32),
+                                emb_loc.astype(jnp.float32).T,
+                                precision=jax.lax.Precision.HIGHEST)
             if extras:
                 scores = scores * extras[0].astype(jnp.float32)[:, 0][None]
         masked = jnp.where(virt_loc < NOT_PROBED, scores, NEG_INF)

@@ -79,6 +79,13 @@ an interrupted write can never leave a torn payload behind (and a torn
 file from an older writer is caught by the checksum / container parse and
 degrades like any corrupt blob).
 
+UNDO COPIES (crash recovery, core/durability.py): once :meth:`track_undo`
+is on, the first write of an op that replaces or deletes a key's blob
+first hard-links the old blob to ``<blob>.undo``.  The index drops the
+copies (:meth:`discard_undo`) when the op's WAL record has landed; a copy
+that outlives a crash is resolved by :meth:`resolve_undo` — put back if it
+is the blob the durable state claims, deleted otherwise.
+
 MULTI-TENANCY: keys may be plain ints (single-tenant, the historical
 contract — paths and accounting unchanged) or ``(tenant, cid)`` tuples.
 Tuple keys land in per-tenant ``tenant_<name>/`` subdirectories on disk and
@@ -134,14 +141,27 @@ class StaleCodebookError(CorruptPayloadError):
 
 _CLUSTER_FILE = re.compile(r"^cluster_(\d+)\.npz$")
 _TENANT_DIR = re.compile(r"^tenant_([A-Za-z0-9._-]+)$")
-# tmp files OUR writers leave behind when a put/train dies mid-write —
-# the only .tmp names clear() is allowed to sweep (foreign files stay)
-_STALE_TMP = re.compile(r"^(cluster_\d+\.npz|pq_codebook\.npz)\.tmp$")
+_UNDO_FILE = re.compile(r"^cluster_(\d+)\.npz\.undo$")
+_UNDO = ".undo"
+# tmp and undo files OUR writers leave behind when a put/train dies
+# mid-write or an op dies before its WAL record — the only such names
+# clear() is allowed to sweep (foreign files stay)
+_STALE_TMP = re.compile(
+    r"^((cluster_\d+\.npz|pq_codebook\.npz)\.tmp|cluster_\d+\.npz\.undo)$")
 _NAMESPACE_RE = re.compile(r"^[A-Za-z0-9._-]*$")
 _CHECKSUM_KEY = "crc"
 
 #: blob key: a bare cluster id, or ``(tenant, cid)`` on a shared backend
 StorageKey = Union[int, Tuple[str, int]]
+
+
+def _file_crc(path: str) -> Optional[int]:
+    """The ``"crc"`` member of a blob file, or None if it cannot be read."""
+    try:
+        with np.load(path) as z:
+            return int(np.asarray(z[_CHECKSUM_KEY]).reshape(-1)[0])
+    except Exception:
+        return None
 
 
 def payload_checksum(payload: Dict[str, np.ndarray]) -> int:
@@ -179,6 +199,8 @@ class StorageBackend:
         self._mem: Dict[StorageKey, Dict[str, np.ndarray]] = {}
         self._nbytes: Dict[StorageKey, int] = {}    # stored payload bytes
         self._crcs: Dict[StorageKey, int] = {}      # payload CRC at put time
+        # keys with an undo copy since the last discard; None: not tracking
+        self._undo: Optional[set] = None
         self.root: Optional[str] = None
         self._base: Optional[str] = None            # root[/namespace]
         if mode != "memory":
@@ -454,6 +476,7 @@ class StorageBackend:
             self._claim_root()
             path = self._path(key)
             os.makedirs(os.path.dirname(path), exist_ok=True)
+            self._stash_undo(key, path)
             tmp = path + ".tmp"
             try:
                 with open(tmp, "wb") as f:
@@ -522,13 +545,59 @@ class StorageBackend:
             crc = int(np.asarray(
                 self._mem[key][_CHECKSUM_KEY]).reshape(-1)[0])
         else:
-            try:
-                with np.load(self._path(key)) as z:
-                    crc = int(np.asarray(z[_CHECKSUM_KEY]).reshape(-1)[0])
-            except Exception:
+            crc = _file_crc(self._path(key))
+            if crc is None:
                 raise KeyError(key)
         self._crcs[key] = crc
         return crc
+
+    # ---- undo copies (module docstring) ------------------------------------
+    def track_undo(self):
+        """From now on keep an undo copy of each blob an op replaces or
+        deletes, until :meth:`discard_undo`.  Memory mode has nothing to
+        recover after a crash, so it keeps none."""
+        if self.mode != "memory" and self._undo is None:
+            self._undo = set()
+
+    def _stash_undo(self, key: StorageKey, path: str):
+        if self._undo is None or key in self._undo \
+                or not os.path.exists(path):
+            return                  # only the op's FIRST write is pre-op
+        undo = path + _UNDO
+        if os.path.exists(undo):    # a copy whose op committed: dead
+            os.remove(undo)
+        os.link(path, undo)
+        self._undo.add(key)
+
+    def discard_undo(self):
+        """The op's WAL record landed: its undo copies are dead weight."""
+        for key in self._undo or ():
+            undo = self._path(key) + _UNDO
+            if os.path.exists(undo):
+                os.remove(undo)
+        if self._undo:
+            self._undo.clear()
+
+    def undo_keys(self) -> List[StorageKey]:
+        """Keys with an undo copy on disk: an op died before its record."""
+        return [] if self.mode == "memory" else self._scan(_UNDO_FILE)
+
+    def resolve_undo(self, key: StorageKey, durable_crc: Optional[int]
+                     ) -> bool:
+        """Recovery: put the undo copy of ``key`` back if it is the blob
+        the durable state claims (``durable_crc``) and the live blob is
+        not — returns True — else delete the copy."""
+        path = self._path(key)
+        undo = path + _UNDO
+        back = (durable_crc is not None and _file_crc(path) != durable_crc
+                and _file_crc(undo) == durable_crc)
+        if back:
+            os.replace(undo, path)
+            self._crcs[key] = durable_crc
+            self._nbytes[key] = os.stat(path).st_size
+        else:
+            os.remove(undo)
+        return back
 
     def delete(self, key: int):
         self._nbytes.pop(key, None)
@@ -538,6 +607,7 @@ class StorageBackend:
             return
         path = self._path(key)
         if os.path.exists(path):
+            self._stash_undo(key, path)
             os.remove(path)
         # a crashed put can strand its temp file next to the blob: sweep it
         # so the directory never accumulates torn garbage
@@ -546,15 +616,18 @@ class StorageBackend:
 
     def clear(self):
         """Drop every stored cluster (index rebuilds) — plus, on disk
-        roots, the persisted PQ codebook file and any stale ``.tmp`` files
-        a crashed put left behind, so a rebuild on this root never decodes
-        against a leftover codebook version or trips over torn garbage.
+        roots, the persisted PQ codebook file and any stale ``.tmp`` or
+        ``.undo`` files a crashed put or op left behind, so a rebuild on
+        this root never decodes against a leftover codebook version or
+        trips over torn garbage.
         (The in-memory codebook is kept: a rebuild's ``train_pq`` bumps
         its version, preserving the stale-blob invalidation semantics.)"""
         for key in self.keys():
             self.delete(key)
         self._nbytes.clear()
         self._crcs.clear()
+        if self._undo:
+            self._undo.clear()
         if self.mode == "memory":
             return
         cb_path = os.path.join(self._base, _CODEBOOK_FILE)
@@ -577,19 +650,22 @@ class StorageBackend:
     def keys(self) -> List[StorageKey]:
         if self.mode == "memory":
             return list(self._mem)
+        return self._scan(_CLUSTER_FILE)
+
+    def _scan(self, pattern: "re.Pattern") -> List[StorageKey]:
         # foreign files in a user-supplied root are not ours to touch:
-        # only cluster_<n>.npz blobs and tenant_<name>/ subdirectories
-        # of our base directory are enumerated
+        # only our file names in the base directory and its tenant_<name>/
+        # subdirectories are enumerated
         out: List[StorageKey] = [
             int(m.group(1)) for m in
-            (_CLUSTER_FILE.match(f) for f in os.listdir(self._base)) if m]
+            (pattern.match(f) for f in os.listdir(self._base)) if m]
         for entry in os.listdir(self._base):
             td = _TENANT_DIR.match(entry)
             if not td or not os.path.isdir(os.path.join(self._base, entry)):
                 continue
             tenant = td.group(1)
             for f in os.listdir(os.path.join(self._base, entry)):
-                m = _CLUSTER_FILE.match(f)
+                m = pattern.match(f)
                 if m:
                     out.append((tenant, int(m.group(1))))
         return out
@@ -710,6 +786,19 @@ class TenantStorageView:
     def keys(self) -> List[int]:
         return [k[1] for k in self.backend.keys()
                 if isinstance(k, tuple) and k[0] == self.tenant]
+
+    def track_undo(self):
+        self.backend.track_undo()
+
+    def discard_undo(self):
+        self.backend.discard_undo()
+
+    def undo_keys(self) -> List[int]:
+        return [k[1] for k in self.backend.undo_keys()
+                if isinstance(k, tuple) and k[0] == self.tenant]
+
+    def resolve_undo(self, cid: int, durable_crc: Optional[int]) -> bool:
+        return self.backend.resolve_undo(self._k(cid), durable_crc)
 
     def clear(self):
         """Drop THIS tenant's blobs only (its index rebuilds)."""

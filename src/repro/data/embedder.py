@@ -7,9 +7,10 @@
   so one call over many texts is one feature matmul, not a Python loop per
   character.
 * :class:`ModelEmbedder` — the real thing: wraps the gte-base JAX model
-  (``repro.models.encode``) behind the tokenizer.  Batches are padded to
-  power-of-two row counts so the jitted encode compiles once per bucket and
-  a coalesced regeneration call is a single device program.
+  (``repro.models.encode``) behind the tokenizer.  A call runs as
+  microbatches of at most ``MAX_BATCH`` rows, each padded to a power-of-two
+  row count, so the jitted encode compiles once per bucket and a corpus
+  build never compiles a corpus-sized program.
 * :class:`TableEmbedder` — oracle for synthetic corpora: chunk texts carry a
   ``doc-<id>`` prefix that resolves to a precomputed vector, so regeneration
   at retrieval time reproduces indexing-time embeddings exactly (the paper's
@@ -112,6 +113,10 @@ class TableEmbedder:
 class ModelEmbedder:
     """gte-base-en-v1.5 (paper Table 3) running in this framework."""
 
+    # rows per encode program: at 128 tokens the full-width model needs
+    # about 1 GB of temporaries, so a corpus build fits beside a generator
+    MAX_BATCH = 256
+
     def __init__(self, cfg=None, params=None, *, max_len: int = 128,
                  seed: int = 0, reduced: bool = True):
         import jax
@@ -139,20 +144,29 @@ class ModelEmbedder:
             p, self.cfg, {"tokens": toks, "attn_mask": mask}))
 
     def embed(self, texts: Sequence[str]) -> np.ndarray:
-        """Batched encode: rows are padded to the next power-of-two batch
-        size so the jitted program compiles once per bucket — a coalesced
-        regeneration over many clusters is ONE device program."""
+        """Batched encode in microbatches of at most ``MAX_BATCH`` rows.
+        Each microbatch is padded to the next power-of-two row count, so
+        the jitted program compiles once per bucket and no call, however
+        large (a corpus build), compiles a program bigger than
+        ``MAX_BATCH`` rows.  All microbatches are dispatched before the
+        first result is read back."""
         self.calls += 1
         self.chars_embedded += sum(len(t) for t in texts)
         toks, mask = self.tokenizer.encode_batch(list(texts), self.max_len)
         b = toks.shape[0]
-        bucket = 1 << max(0, (b - 1).bit_length())
-        if bucket > b:                   # pad rows; padded rows sliced off
-            pad = ((0, bucket - b), (0, 0))
-            toks = np.pad(toks, pad)
-            mask = np.pad(mask, pad)
-            mask[b:, 0] = 1              # keep padded rows mask-valid
-        out = np.asarray(self._jit_encode(self.params, toks, mask))
-        return out[:b]
+        parts = []
+        for s in range(0, b, self.MAX_BATCH):
+            t, m = toks[s:s + self.MAX_BATCH], mask[s:s + self.MAX_BATCH]
+            n = t.shape[0]
+            bucket = 1 << max(0, (n - 1).bit_length())
+            if bucket > n:               # pad rows; padded rows sliced off
+                pad = ((0, bucket - n), (0, 0))
+                t = np.pad(t, pad)
+                m = np.pad(m, pad)
+                m[n:, 0] = 1             # keep padded rows mask-valid
+            parts.append((n, self._jit_encode(self.params, t, m)))
+        if not parts:
+            return np.zeros((0, self.dim), np.float32)
+        return np.concatenate([np.asarray(o)[:n] for n, o in parts])
 
     __call__ = embed

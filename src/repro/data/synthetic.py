@@ -9,6 +9,12 @@ the three properties EdgeRAG exploits (Table 2, Fig. 4, Fig. 5):
      revisit clusters Zipf-style (Table 2 'Reuse Ratio' column);
   3. per-chunk text whose char count drives the embedding cost model.
 
+Each topic spells its words through a letter permutation of its own, so a
+text encoder (even one with random weights) sees topic structure in the
+tokens: passages of one topic share a vocabulary that other topics do not.
+Each query also carries a text in its topic's vocabulary
+(``query_texts``), for the paths that embed queries with a model.
+
 Each dataset entry carries the paper's Table 2 identity (records, embedding
 bytes, fits-in-memory flag) so benchmarks can scale the cost model's device
 memory to reproduce the in/out-of-memory regimes at laptop record counts.
@@ -30,7 +36,8 @@ _WORDS = ("the quick brown fox jumps over lazy dog alpha beta gamma delta "
 
 @dataclasses.dataclass
 class BeirSpec:
-    """Paper Table 2 row."""
+    """Paper Table 2 row, plus the dataset's mean passage length in words
+    (BEIR, arXiv 2104.08663, Table 1)."""
     name: str
     corpus_mb: float
     n_records: int
@@ -40,15 +47,16 @@ class BeirSpec:
     reuse_ratio: float
     fits_in_memory: bool
     slo_s: float
+    doc_words: float
 
 
 BEIR_SPECS: Dict[str, BeirSpec] = {
-    "scidocs": BeirSpec("scidocs", 86, 3_600, 113 << 20, 1157, 2000, 1.73, True, 1.0),
-    "fiqa": BeirSpec("fiqa", 130, 25_000, 217 << 20, 2974, 13286, 4.47, True, 1.0),
-    "quora": BeirSpec("quora", 641, 523_000, int(1.5 * 2**30), 15672, 30000, 1.91, True, 1.0),
-    "nq": BeirSpec("nq", 4_600, 2_680_000, int(8.3 * 2**30), 8186, 10235, 1.25, False, 1.5),
-    "hotpotqa": BeirSpec("hotpotqa", 11_000, 5_420_000, int(15.4 * 2**30), 15519, 22098, 1.42, False, 1.5),
-    "fever": BeirSpec("fever", 7_500, 5_230_000, int(18.5 * 2**30), 5783, 13922, 2.41, False, 1.5),
+    "scidocs": BeirSpec("scidocs", 86, 3_600, 113 << 20, 1157, 2000, 1.73, True, 1.0, 176.19),
+    "fiqa": BeirSpec("fiqa", 130, 25_000, 217 << 20, 2974, 13286, 4.47, True, 1.0, 132.32),
+    "quora": BeirSpec("quora", 641, 523_000, int(1.5 * 2**30), 15672, 30000, 1.91, True, 1.0, 11.44),
+    "nq": BeirSpec("nq", 4_600, 2_680_000, int(8.3 * 2**30), 8186, 10235, 1.25, False, 1.5, 78.88),
+    "hotpotqa": BeirSpec("hotpotqa", 11_000, 5_420_000, int(15.4 * 2**30), 15519, 22098, 1.42, False, 1.5, 46.30),
+    "fever": BeirSpec("fever", 7_500, 5_230_000, int(18.5 * 2**30), 5783, 13922, 2.41, False, 1.5, 84.76),
 }
 
 
@@ -65,6 +73,7 @@ class SyntheticDataset:
     query_topic: np.ndarray             # (nq,)
     embedder: TableEmbedder
     scale: float = 1.0                  # n_records / spec.n_records
+    query_texts: List[str] = dataclasses.field(default_factory=list)
 
     @property
     def n(self) -> int:
@@ -91,11 +100,24 @@ class SyntheticDataset:
                    .tolist())
 
 
-def _make_text(did: int, n_chars: int, rng: np.random.Generator) -> str:
-    words = [f"doc-{did}"]
+_LETTERS = "abcdefghijklmnopqrstuvwxyz"
+
+
+def _topic_words(topic: int) -> List[str]:
+    """The topic's vocabulary: every base word spelled through a letter
+    permutation of the topic's own.  Lengths are kept, so a text's char
+    count and the random draws that build it do not depend on the topic."""
+    perm = np.random.default_rng(topic).permutation(len(_LETTERS))
+    table = str.maketrans(_LETTERS, "".join(_LETTERS[i] for i in perm))
+    return [w.translate(table) for w in _WORDS]
+
+
+def _make_text(head: str, n_chars: int, rng: np.random.Generator,
+               vocab: Sequence[str]) -> str:
+    words = [head]
     ln = len(words[0])
     while ln < n_chars:
-        w = _WORDS[int(rng.integers(len(_WORDS)))]
+        w = vocab[int(rng.integers(len(vocab)))]
         words.append(w)
         ln += len(w) + 1
     return " ".join(words)[:max(n_chars, len(words[0]))]
@@ -124,13 +146,14 @@ def generate_dataset(name: str = "synthetic", n_records: int = 2000,
     table: Dict[int, np.ndarray] = {}
     did = 0
     for t, sz in enumerate(sizes):
+        vocab = _topic_words(t)
         vecs = topics[t][None] + noise * rng.standard_normal((sz, dim))
         vecs = (vecs / np.linalg.norm(vecs, axis=1, keepdims=True)
                 ).astype(np.float32)
         for v in vecs:
             chars = max(40, int(rng.normal(mean_chunk_chars,
                                            mean_chunk_chars * 0.3)))
-            texts.append(_make_text(did, chars, rng))
+            texts.append(_make_text(f"doc-{did}", chars, rng, vocab))
             table[did] = v
             embs.append(v)
             topic_of_chunk.append(t)
@@ -148,6 +171,10 @@ def generate_dataset(name: str = "synthetic", n_records: int = 2000,
     q_vecs = (q_vecs / np.linalg.norm(q_vecs, axis=1, keepdims=True)
               ).astype(np.float32)
     q_chars = rng.integers(40, 160, size=n_queries)
+    # query texts draw from their own stream: the corpus above is unchanged
+    q_rng = np.random.default_rng([seed, 1])
+    q_texts = [_make_text(f"query-{qi}", int(c), q_rng, _topic_words(int(t)))
+               for qi, (c, t) in enumerate(zip(q_chars, q_topics))]
 
     ds = SyntheticDataset(
         name=name, spec=spec,
@@ -157,12 +184,14 @@ def generate_dataset(name: str = "synthetic", n_records: int = 2000,
         query_embs=q_vecs, query_chars=q_chars,
         query_topic=np.asarray(q_topics),
         embedder=TableEmbedder(table, dim),
-        scale=(n_records / spec.n_records) if spec else 1.0)
+        scale=(n_records / spec.n_records) if spec else 1.0,
+        query_texts=q_texts)
     return ds
 
 
 def scaled_beir(name: str, n_records: int = 3000, dim: int = 64,
-                n_queries: int = 200, seed: int = 0) -> SyntheticDataset:
+                n_queries: int = 200, seed: int = 0,
+                mean_chunk_chars: int = 300) -> SyntheticDataset:
     """Scaled-down analogue of a Table 2 dataset (same skew structure).
 
     The number of topics scales with sqrt(n) and the Zipf parameter is tuned
@@ -175,4 +204,5 @@ def scaled_beir(name: str, n_records: int = 3000, dim: int = 64,
     n_topics = max(16, int(np.sqrt(n_records) * 2))
     return generate_dataset(name=name, n_records=n_records, dim=dim,
                             n_topics=n_topics, n_queries=n_queries,
-                            seed=seed, zipf_a=zipf_a)
+                            seed=seed, zipf_a=zipf_a,
+                            mean_chunk_chars=mean_chunk_chars)

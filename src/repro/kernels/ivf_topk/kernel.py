@@ -13,10 +13,15 @@ candidate blocks of one query block.  Each candidate block is therefore
 streamed from HBM once per *query block* instead of once per query: a batch
 of B queries costs ceil(B / BLOCK_Q) passes over the candidates, not B.
 
-Top-k maintenance is k iterations of a row-vectorized (argmax, mask) over
-the (BLOCK_Q, k + BLOCK_N) candidate matrix — all BLOCK_Q rows advance per
-iteration (pure VPU work; k is small, ≤ 128).  The single-query path is the
-degenerate case BLOCK_Q = 1.
+Top-k maintenance is k iterations of a row-vectorized select over the
+running (BLOCK_Q, k) best and the (BLOCK_Q, BLOCK_N) score block — all
+BLOCK_Q rows advance per iteration (pure VPU work; k is small, ≤ 128).  It
+uses only what Mosaic lowers: f32 max/min lane reductions, iota compares
+and selects.  No gather, no integer argmin and no dynamic-lane store: the
+winning column is the min column index (exact in f32) among the maximal
+scores, its id is read out by a masked reduce, and lane i of the new
+running block is written by ``where(iota_k == i, ...)``.  The single-query
+path is the degenerate case BLOCK_Q = 1.
 
 BlockSpec tiling: emb block (BLOCK_N, D) f32 in VMEM (default 512×768×4 ≈
 1.5 MiB), query block (BLOCK_Q, D), outputs (BLOCK_Q, k).  D stays whole:
@@ -33,37 +38,51 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = -1e30
+IDX_SENTINEL = 2**30            # id of an empty running lane (exact in f32)
 
 
-def _topk_merge_rows(scores, base_idx, run_vals, run_idx, k: int):
+def _topk_merge_rows(scores, base, run_vals, run_idx, k: int):
     """Merge a block's scores (BQ, BN) into the running (BQ, k) best.
 
-    Vectorized across the BQ query rows: each of the k iterations does one
-    row-wise argmax over the (BQ, k + BN) candidate matrix and masks the
-    selected column per row.  Ties break toward the lower column index —
-    running entries (already sorted, earlier N blocks) win over new
-    candidates, matching ``jax.lax.top_k`` order.
+    ``base`` is the block's first global row.  Ties break toward the lower
+    row id, matching ``jax.lax.top_k``: running entries come from earlier
+    blocks, so at equal score they win over the block, and inside either
+    part the lower column is the lower id.  Column indices and ids (rows
+    < 2**24, or ``IDX_SENTINEL``) are exact in f32, so every reduction
+    runs in f32.  A consumed candidate drops to -inf, below every masked
+    (``NEG_INF``) score, so it is never selected twice.
     """
-    bq = scores.shape[0]
-    cand_vals = jnp.concatenate([run_vals, scores], axis=1)   # (BQ, k + BN)
-    cand_idx = jnp.concatenate(
-        [run_idx, jnp.broadcast_to(base_idx[None], scores.shape)], axis=1)
-    col = jax.lax.broadcasted_iota(jnp.int32, cand_vals.shape, 1)
+    bq, bn = scores.shape
+    col_k = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1).astype(
+        jnp.float32)
+    col_n = jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1).astype(
+        jnp.float32)
+    run_idx_f = run_idx.astype(jnp.float32)
 
     def body(i, carry):
-        vals, out_v, out_i = carry
-        j = jnp.argmax(vals, axis=1)                          # (BQ,)
-        best_v = jnp.take_along_axis(vals, j[:, None], axis=1)
-        best_i = jnp.take_along_axis(cand_idx, j[:, None], axis=1)
-        out_v = jax.lax.dynamic_update_slice(out_v, best_v, (0, i))
-        out_i = jax.lax.dynamic_update_slice(out_i, best_i, (0, i))
-        vals = jnp.where(col == j[:, None], NEG_INF, vals)
-        return vals, out_v, out_i
+        run_v, blk_v, out_v, out_i = carry
+        m = jnp.maximum(jnp.max(run_v, axis=1, keepdims=True),
+                        jnp.max(blk_v, axis=1, keepdims=True))   # (BQ, 1)
+        jr = jnp.min(jnp.where(run_v == m, col_k, float(k)), axis=1,
+                     keepdims=True)
+        jb = jnp.min(jnp.where(blk_v == m, col_n, float(bn)), axis=1,
+                     keepdims=True)
+        from_run = jr < k
+        run_id = jnp.max(jnp.where(col_k == jr, run_idx_f, -1.0), axis=1,
+                         keepdims=True)
+        best_i = jnp.where(from_run, run_id.astype(jnp.int32),
+                           base + jb.astype(jnp.int32))
+        lane = col_k == i
+        out_v = jnp.where(lane, m, out_v)
+        out_i = jnp.where(lane, best_i, out_i)
+        run_v = jnp.where(from_run & (col_k == jr), -jnp.inf, run_v)
+        blk_v = jnp.where(~from_run & (col_n == jb), -jnp.inf, blk_v)
+        return run_v, blk_v, out_v, out_i
 
-    init = (cand_vals,
+    init = (run_vals, scores,
             jnp.full((bq, k), NEG_INF, jnp.float32),
-            jnp.full((bq, k), jnp.int32(2**30), jnp.int32))
-    _, out_v, out_i = jax.lax.fori_loop(0, k, body, init)
+            jnp.full((bq, k), IDX_SENTINEL, jnp.int32))
+    _, _, out_v, out_i = jax.lax.fori_loop(0, k, body, init)
     return out_v, out_i
 
 
@@ -74,15 +93,17 @@ def _kernel(valid_ref, emb_ref, q_ref, out_v_ref, out_i_ref,
     @pl.when(nb == 0)
     def _init():
         run_v[...] = jnp.full((block_q, k), NEG_INF, jnp.float32)
-        run_i[...] = jnp.full((block_q, k), jnp.int32(2**30), jnp.int32)
+        run_i[...] = jnp.full((block_q, k), IDX_SENTINEL, jnp.int32)
 
     emb = emb_ref[...].astype(jnp.float32)                   # (BN, D)
     q = q_ref[...].astype(jnp.float32)                       # (BQ, D)
     scores = jax.lax.dot_general(                            # (BQ, BN) via MXU
         q, emb, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    base = nb * block_n + jax.lax.iota(jnp.int32, block_n)
-    scores = jnp.where((base < valid_ref[0])[None], scores, NEG_INF)
+    base = nb * block_n
+    row = base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    scores = jnp.where(row < valid_ref[0], scores, NEG_INF)
     v, i = _topk_merge_rows(scores, base, run_v[...], run_i[...], k)
     run_v[...] = v
     run_i[...] = i
@@ -104,6 +125,9 @@ def topk_ip_pallas(embs, queries, k: int, *, block_n: int = 512,
     block multiples internally; padded outputs are sliced off.
     """
     n, d = embs.shape
+    if n >= 2**24:
+        raise ValueError(f"{n} candidate rows: row ids must stay below "
+                         "2**24 to be exact in the f32 merge")
     q = queries.shape[0]
     block_q = max(1, min(block_q, q))
     n_pad = (-n) % block_n

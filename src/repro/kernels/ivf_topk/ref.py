@@ -12,6 +12,8 @@ import jax.numpy as jnp
 
 def topk_ip_ref(embs: jax.Array, queries: jax.Array, k: int):
     """embs: (N, D); queries: (Q, D) -> (scores (Q, k), idx (Q, k) int32)."""
-    scores = queries.astype(jnp.float32) @ embs.astype(jnp.float32).T  # (Q, N)
+    scores = jnp.matmul(queries.astype(jnp.float32),                 # (Q, N)
+                        embs.astype(jnp.float32).T,
+                        precision=jax.lax.Precision.HIGHEST)
     vals, idx = jax.lax.top_k(scores, k)
     return vals, idx.astype(jnp.int32)

@@ -108,7 +108,9 @@ def slab_topk_ref(emb: jax.Array, queries: jax.Array, virt: jax.Array,
     if luts is not None:
         scores = pq_adc_scores(emb, luts.astype(jnp.float32))
     else:
-        scores = queries.astype(jnp.float32) @ emb.astype(jnp.float32).T
+        scores = jnp.matmul(queries.astype(jnp.float32),
+                            emb.astype(jnp.float32).T,
+                            precision=jax.lax.Precision.HIGHEST)
         if scales is not None:
             scores = scores * scales.astype(jnp.float32)[:, 0][None, :]
     masked = jnp.where(virt < NOT_PROBED, scores, NEG_INF)

@@ -24,25 +24,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-try:                                    # jax >= 0.5
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-# keyed on the signature, not the import location: the public promotion of
-# shard_map and the check_rep -> check_vma rename were separate changes
-_REP_KW = ("check_vma" if "check_vma" in
-           inspect.signature(_shard_map).parameters else "check_rep")
-
-
-def shard_map(f, *args, check_vma=False, **kwargs):
-    """jax version shim: check_vma (>=0.5) == check_rep (0.4.x)."""
-    return _shard_map(f, *args, **{_REP_KW: check_vma}, **kwargs)
 
 NEG_INF = -1e30
 

@@ -407,11 +407,16 @@ class RAGEngine:
 
 
 class GeneratorModel:
-    """The generation model (Sheared-LLaMA stand-in) on the JAX substrate."""
+    """The generation model (Sheared-LLaMA stand-in) on the JAX substrate.
+
+    ``dtype`` is the parameter dtype (compute stays float32).  Seeded
+    parameters are initialised in one jitted program, so a bf16 model never
+    holds a float32 copy of itself on the device."""
 
     def __init__(self, cfg=None, params=None, *, seed: int = 0,
-                 reduced: bool = True, max_prompt: int = 128):
+                 reduced: bool = True, max_prompt: int = 128, dtype=None):
         import jax
+        import jax.numpy as jnp
         from repro.configs import get_config
         from repro.models import decode_step, init_cache, init_params, prefill
         if cfg is None:
@@ -420,7 +425,9 @@ class GeneratorModel:
                 cfg = cfg.reduced(num_layers=2, d_model=256)
         self.cfg = cfg
         if params is None:
-            params = init_params(cfg, jax.random.PRNGKey(seed))
+            dtype = dtype or jnp.float32
+            params = jax.jit(lambda key: init_params(cfg, key, dtype))(
+                jax.random.PRNGKey(seed))
         self.params = params
         self.tokenizer = HashingTokenizer(vocab_size=cfg.vocab_size)
         self.max_prompt = max_prompt

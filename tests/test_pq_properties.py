@@ -159,7 +159,7 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=15, deadline=None)
     @given(n=st.integers(2, 64), d=st.sampled_from([8, 15, 33]),
            m=st.sampled_from([4, 8]), seed=st.integers(0, 10_000))
-    def test_memmap_lifecycle_fuzz(n, d, m, seed, tmp_path_factory=None):
+    def test_memmap_lifecycle_fuzz(n, d, m, seed):
         import tempfile
         with tempfile.TemporaryDirectory() as td:
             check_memmap_lifecycle(td, n, d, m, seed)

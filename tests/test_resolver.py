@@ -30,7 +30,8 @@ def _fresh(ds, **kw):
 
 def test_plan_driven_batch_matches_sequential_search(ds):
     """Acceptance: ResolutionPlan-driven search_batch equals the sequential
-    per-query search on ids AND scores (fp32 tier)."""
+    per-query search on ids exactly and on scores to f32 rounding (fp32
+    tier; Q=1 and Q=20 matmuls reduce in different orders)."""
     seq = _fresh(ds, cache_bytes=1 << 20)
     bat = _fresh(ds, cache_bytes=1 << 20)
     nq = 20
@@ -41,7 +42,7 @@ def test_plan_driven_batch_matches_sequential_search(ds):
         s_vals.append(vals[0])
     b_ids, b_vals, _ = bat.search_batch(ds.query_embs[:nq], 10, 5)
     assert np.array_equal(np.stack(s_ids), b_ids)
-    assert np.array_equal(np.stack(s_vals), b_vals)
+    np.testing.assert_allclose(np.stack(s_vals), b_vals, rtol=1e-6, atol=1e-6)
 
 
 def test_plan_structure(ds):

@@ -83,7 +83,8 @@ def test_one_tenant_router_matches_standalone(corpora):
 
 def test_mixed_batch_fused_matches_silos(corpora):
     """Interleaved 3-tenant batch through ONE fused slab launch ==
-    serving each tenant's queries through its own standalone index."""
+    serving each tenant's queries through its own standalone index: ids
+    exactly, scores to f32 rounding (the batch shapes differ)."""
     cost = _cost()
     router = _router(corpora, cost)
     silos = [_standalone(ds, cost, cache_bytes=CACHE) for ds in corpora]
@@ -102,7 +103,8 @@ def test_mixed_batch_fused_matches_silos(corpora):
                 for silo, ds in zip(silos, corpora)]
         for gqi, (t, qi) in enumerate(local):
             np.testing.assert_array_equal(mids[gqi], refs[t][0][qi])
-            np.testing.assert_array_equal(mvals[gqi], refs[t][1][qi])
+            np.testing.assert_allclose(mvals[gqi], refs[t][1][qi],
+                                       rtol=1e-6, atol=1e-6)
 
 
 def test_cross_tenant_plan_keys_are_tenant_scoped(corpora):
