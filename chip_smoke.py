@@ -191,11 +191,11 @@ def one_chip():
     print(f"compile: {compiles['seconds']:.3f} s in {compiles['programs']} "
           f"programs so far")
     print("per-request wall seconds (bring-up run, not a benchmark; "
-          "amortised over each batch):")
+          "decode amortised over each batch):")
     for bi, w in enumerate(run.batch_wall_s):
         rs = run.responses[bi * args.batch:(bi + 1) * args.batch]
         print(f"  batch {bi} ({len(rs)} requests, {w:.4f} s, compiles "
-              f"included): retrieval "
+              f"included): batch start to first token "
               f"{[round(r.ttft_wall_s, 4) for r in rs]}, decode "
               f"{[round(r.decode_wall_s, 4) for r in rs]}")
     tiers = serve.counts(run)

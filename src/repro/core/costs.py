@@ -19,7 +19,6 @@ modeled at LPDDR5 speeds.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Optional
 
 BYTES_PER_EMBEDDING_F32 = 768 * 4
@@ -195,11 +194,3 @@ class LatencyBreakdown:
         d.pop("STAGE_FIELDS", None)
         return d | {"retrieval_s": self.retrieval_s}
 
-
-class WallTimer:
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.elapsed = time.perf_counter() - self.t0

@@ -86,9 +86,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.costs import EdgeCostModel, WallTimer
+from repro.core.costs import EdgeCostModel
 from repro.core.faults import CrashInjector
 from repro.core.maintenance import OP_MERGE, OP_RESTORE, OP_SPLIT
+from repro.core.tracing import span
 
 WAL_MAGIC = b"EDGEWAL1"
 _WAL_HEADER = struct.Struct("<II")
@@ -690,7 +691,7 @@ def recover_index(index, dur: Durability, *,
     re-derivation for the deferred queue the crash threw away.  Attaches
     ``dur`` to the index and finishes with a fresh checkpoint."""
     rep = report or RecoveryReport(tenant=dur.tenant)
-    with WallTimer() as t:
+    with span("recover.index") as t:
         rep.torn_bytes = dur.wal.truncate_torn_tail()
         found = IndexSnapshot.newest_valid(dur.dir)
         if found is None:

@@ -106,13 +106,14 @@ import numpy as np
 
 from repro.core.cache_policy import (CostAwareLFUCache,
                                      MinLatencyThresholdController)
-from repro.core.costs import EdgeCostModel, LatencyBreakdown, WallTimer
+from repro.core.costs import EdgeCostModel, LatencyBreakdown
 from repro.core.faults import DegradationPolicy
 from repro.core.kmeans import kmeans
 from repro.core.maintenance import (OP_DROP_STORE, OP_MERGE, OP_RESTORE,
                                     OP_SPLIT, MaintenanceScheduler)
 from repro.core.resolver import ClusterResolver, ResolutionPlan, SlabPayload
 from repro.core.storage import StorageBackend
+from repro.core.tracing import span
 from repro.kernels.ivf_topk.ops import topk_ip
 from repro.kernels.slab_topk.ops import NOT_PROBED, slab_topk
 
@@ -448,9 +449,10 @@ class EdgeRAGIndex:
         dead clusters this is exactly a ``min(nprobe, nlist)`` top-k.
         """
         n_dead = sum(not c.active or c.size == 0 for c in self.clusters)
-        _, probed_all = topk_ip(self.centroids, queries,
-                                min(nprobe + n_dead, self.nlist))
-        probed_all = np.asarray(probed_all)
+        with span("s1.probe", rows=queries.shape[0]):
+            _, probed_all = topk_ip(self.centroids, queries,
+                                    min(nprobe + n_dead, self.nlist))
+            probed_all = np.asarray(probed_all)
         return [[int(c) for c in probed_all[qi]
                  if c >= 0 and self.clusters[int(c)].active
                  and self.clusters[int(c)].size > 0][:nprobe]
@@ -465,26 +467,27 @@ class EdgeRAGIndex:
         effective nprobe) first when deadline budgets are present.  The
         deadlines / policy / shed counts ride on the plan so execute-time
         rungs 2-3 and ``search_batch``'s accounting see them."""
-        shed: Optional[List[int]] = None
-        if deadlines is not None:
-            nq = len(probed_per_q)
-            assert len(deadlines) == nq, \
-                f"{len(deadlines)} deadlines for {nq} queries"
-            policy = policy or DegradationPolicy()
-            centroid_s = (self.cost.mem_load_latency(self.centroids.nbytes)
-                          + self.cost.search_latency(self.nlist, self.dim))
-            base = [centroid_s
-                    + (self.cost.embed_latency(int(query_chars[qi]))
-                       if query_chars is not None and query_chars[qi]
-                       else 0.0)
-                    for qi in range(nq)]
-            probed_per_q, shed = policy.trim_probes(self, probed_per_q,
-                                                    deadlines, base)
-        plan = self.resolver.plan(probed_per_q)
-        if deadlines is not None:
-            plan.deadlines = list(deadlines)
-            plan.policy = policy
-            plan.shed_probes = shed
+        with span("s1.tier_plan"):
+            shed: Optional[List[int]] = None
+            if deadlines is not None:
+                nq = len(probed_per_q)
+                assert len(deadlines) == nq, \
+                    f"{len(deadlines)} deadlines for {nq} queries"
+                policy = policy or DegradationPolicy()
+                centroid_s = (self.cost.mem_load_latency(self.centroids.nbytes)
+                              + self.cost.search_latency(self.nlist, self.dim))
+                base = [centroid_s
+                        + (self.cost.embed_latency(int(query_chars[qi]))
+                           if query_chars is not None and query_chars[qi]
+                           else 0.0)
+                        for qi in range(nq)]
+                probed_per_q, shed = policy.trim_probes(self, probed_per_q,
+                                                        deadlines, base)
+            plan = self.resolver.plan(probed_per_q)
+            if deadlines is not None:
+                plan.deadlines = list(deadlines)
+                plan.policy = policy
+                plan.shed_probes = shed
         return plan
 
     def plan_batch(self, query_embs: np.ndarray, nprobe: int, *,
@@ -563,7 +566,7 @@ class EdgeRAGIndex:
         queries = np.atleast_2d(np.asarray(query_embs, np.float32))
         nq = queries.shape[0]
         lats = [LatencyBreakdown() for _ in range(nq)]
-        with WallTimer() as t:
+        with span("s1.begin") as t:
             if query_chars is not None:
                 assert len(query_chars) == nq, \
                     f"query_chars has {len(query_chars)} entries for {nq} queries"
@@ -602,7 +605,7 @@ class EdgeRAGIndex:
         coalesced regeneration per regen group (plus any fault retries /
         stalls / degradation sheds).  Owners are charged the single-query
         tier formulas."""
-        with WallTimer() as t:
+        with span("s2.resolve") as t:
             state.payloads = self.resolver.execute(
                 state.plan, state.lats, state.missed, raw=True)
         state.wall_accum_s += t.elapsed
@@ -619,7 +622,7 @@ class EdgeRAGIndex:
                                           state.lats, state.missed)
         nq = state.nq
         probed_per_q = plan.probed_per_q
-        with WallTimer() as t:
+        with span("s3.finish") as t:
             # Pack every unique cluster exactly once into the batch slab;
             # owners are charged the pack copy (and fused dequant for
             # quantized payloads) once per slab.
@@ -639,9 +642,10 @@ class EdgeRAGIndex:
             # per storage representation (slab_score_topk; per-query results
             # identical to the old per-query concat + top-k loop, bitwise on
             # the fp32 tier)
-            out_ids, out_vals, n_valid = slab_score_topk(
-                slab, queries, k, probed_per_q,
-                mesh=state.mesh, shard_axis=state.shard_axis)
+            with span("s3.slab_kernel", rows=slab.total_rows):
+                out_ids, out_vals, n_valid = slab_score_topk(
+                    slab, queries, k, probed_per_q,
+                    mesh=state.mesh, shard_axis=state.shard_axis)
             # PQ segments: every query's ADC tables are built once per
             # batch (l2_pq_lut_s) — charged INSTEAD of any dequant
             has_pq = any(seg.kind == "pq" and seg.rows
@@ -658,12 +662,14 @@ class EdgeRAGIndex:
         # ---- Algorithm 3: adapt the threshold, once per query in order
         # (queries that probed nothing did no level-2 work: no observation,
         # matching the single-query early-return) ----
-        for qi in range(nq):
-            if not probed_per_q[qi]:
-                continue
-            new_thr = self.threshold.observe(missed[qi], lats[qi].retrieval_s)
-            if missed[qi]:
-                self.cache.drop_below_threshold(new_thr)
+        with span("s3.alg3"):
+            for qi in range(nq):
+                if not probed_per_q[qi]:
+                    continue
+                new_thr = self.threshold.observe(missed[qi],
+                                                 lats[qi].retrieval_s)
+                if missed[qi]:
+                    self.cache.drop_below_threshold(new_thr)
         return out_ids, out_vals, lats
 
     def search(self, query_emb: np.ndarray, k: int, nprobe: int,

@@ -11,7 +11,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from repro.core.costs import EdgeCostModel, LatencyBreakdown, WallTimer
+from repro.core.costs import EdgeCostModel, LatencyBreakdown
+from repro.core.tracing import span
 from repro.kernels.ivf_topk.ops import topk_ip
 
 
@@ -43,7 +44,7 @@ class FlatIndex:
         """query (Q, dim) -> (ids (Q,k), scores (Q,k), latency)."""
         query = np.atleast_2d(np.asarray(query, np.float32))
         lat = LatencyBreakdown()
-        with WallTimer() as t:
+        with span("flat.search") as t:
             vals, idx = topk_ip(self._embs, query, k)
             vals, idx = np.asarray(vals), np.asarray(idx)
         lat.wall_s = t.elapsed

@@ -12,8 +12,9 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.core.costs import EdgeCostModel, LatencyBreakdown, WallTimer
+from repro.core.costs import EdgeCostModel, LatencyBreakdown
 from repro.core.kmeans import kmeans
+from repro.core.tracing import span
 from repro.kernels.ivf_topk.ops import topk_ip
 
 
@@ -77,7 +78,7 @@ class IVFIndex:
         query = np.atleast_2d(np.asarray(query, np.float32))
         assert query.shape[0] == 1, "IVF search is per-query"
         lat = LatencyBreakdown()
-        with WallTimer() as t:
+        with span("ivf.search") as t:
             probed = self.probe(query, nprobe)[0]
             lat.n_clusters_probed = len(probed)
             cand_embs, cand_ids, scanned = [], [], 0

@@ -67,6 +67,7 @@ import numpy as np
 
 from repro.core.costs import LatencyBreakdown
 from repro.core.faults import DegradationPolicy, IOOutcome
+from repro.core.tracing import span
 from repro.kernels.slab_topk.ops import NOT_PROBED
 
 TIER_STORAGE = "storage"
@@ -414,8 +415,10 @@ class ClusterResolver:
         modeled I/O seconds with prefill."""
         if plan.storage_clusters and plan.prefetched is None:
             outcomes: List[IOOutcome] = []
-            loaded = self.index.storage.get_many_raw(plan.storage_clusters,
-                                                     outcomes=outcomes)
+            with span("s2.storage_read",
+                      clusters=len(plan.storage_clusters)):
+                loaded = self.index.storage.get_many_raw(
+                    plan.storage_clusters, outcomes=outcomes)
             plan.prefetched = {cid: payload for cid, payload
                                in zip(plan.storage_clusters, loaded)
                                if payload is not None}
@@ -462,8 +465,10 @@ class ClusterResolver:
                 outcomes = plan.io_outcomes or {}
             else:
                 olist: List[IOOutcome] = []
-                loaded = ix.storage.get_many_raw(plan.storage_clusters,
-                                                 outcomes=olist)
+                with span("s2.storage_read",
+                          clusters=len(plan.storage_clusters)):
+                    loaded = ix.storage.get_many_raw(plan.storage_clusters,
+                                                     outcomes=olist)
                 outcomes = {o.key: o for o in olist}
             for cid, payload in zip(plan.storage_clusters, loaded):
                 # fault charges (retries / stalls / backoff) land on the
@@ -726,10 +731,12 @@ class ClusterResolver:
         """ONE ``embed_fn`` call over the group's concatenated texts; yields
         (cid, embeddings view, char count) per cluster."""
         ix = self.index
-        texts_per = [ix.get_chunks(ix.clusters[c].ids.tolist())
-                     for c in cids]
-        flat = [txt for ts in texts_per for txt in ts]
-        embs_all = np.ascontiguousarray(ix.embed_fn(flat), np.float32)
+        with span("s2.regen", clusters=len(cids)) as sp:
+            texts_per = [ix.get_chunks(ix.clusters[c].ids.tolist())
+                         for c in cids]
+            flat = [txt for ts in texts_per for txt in ts]
+            sp.note(rows=len(flat))
+            embs_all = np.ascontiguousarray(ix.embed_fn(flat), np.float32)
         off = 0
         for cid, ts in zip(cids, texts_per):
             sub = embs_all[off:off + len(ts)]

@@ -54,13 +54,14 @@ import numpy as np
 
 from repro.core.cache_policy import (CostAwareLFUCache,
                                      TenantCacheView)
-from repro.core.costs import EdgeCostModel, LatencyBreakdown, WallTimer
+from repro.core.costs import EdgeCostModel, LatencyBreakdown
 from repro.core.edgerag import (BatchSearchState, EdgeRAGIndex,
                                 slab_score_topk)
 from repro.core.faults import DegradationPolicy
 from repro.core.maintenance import FairShareMaintenance
 from repro.core.resolver import ClusterResolver, ResolutionPlan, SlabPayload
 from repro.core.storage import StorageBackend, TenantStorageView
+from repro.core.tracing import span
 
 _TENANT_ID_RE = re.compile(r"^[A-Za-z0-9._-]+$")
 
@@ -306,7 +307,7 @@ class TenantRouter:
                 states[t] = tix.search_begin(sub, k, nprobe, sub_chars,
                                              deadlines=sub_dl, policy=policy,
                                              mesh=mesh, shard_axis=shard_axis)
-        with WallTimer() as timer:
+        with span("s1.tenant_merge") as timer:
             probed_per_q: List[List[TenantKey]] = [[] for _ in range(nq)]
             lats: List[Optional[LatencyBreakdown]] = [None] * nq
             for t, gqis in order.items():
@@ -371,7 +372,7 @@ class TenantRouter:
         assert state.payloads is not None, "search_fetch has not run"
         lats = state.lats
         nq = state.nq
-        with WallTimer() as t:
+        with span("s3.finish") as t:
             slab = self.resolver.pack_slab(state.plan, state.payloads, lats)
             owner = state.plan.owner
             resident = self.memory_bytes()

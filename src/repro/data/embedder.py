@@ -24,6 +24,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
+from repro.core.tracing import span
 from repro.data.tokenizer import HashingTokenizer, _fnv1a
 
 _FNV_BASIS = np.uint64(0xCBF29CE484222325)
@@ -140,8 +141,12 @@ class ModelEmbedder:
     @functools.cached_property
     def _jit_encode(self):
         import jax
-        return jax.jit(lambda p, toks, mask: self._encode(
-            p, self.cfg, {"tokens": toks, "attn_mask": mask}))
+        encode, cfg = self._encode, self.cfg
+
+        def encoder_forward(params, tokens, attn_mask):
+            return encode(params, cfg,
+                          {"tokens": tokens, "attn_mask": attn_mask})
+        return jax.jit(encoder_forward)
 
     def embed(self, texts: Sequence[str]) -> np.ndarray:
         """Batched encode in microbatches of at most ``MAX_BATCH`` rows.
@@ -149,24 +154,33 @@ class ModelEmbedder:
         the jitted program compiles once per bucket and no call, however
         large (a corpus build), compiles a program bigger than
         ``MAX_BATCH`` rows.  All microbatches are dispatched before the
-        first result is read back."""
+        first result is read back.  Spans: ``embed.tokenize`` (tokenizer
+        and row padding), ``embed.encode`` (dispatch through readback)."""
         self.calls += 1
         self.chars_embedded += sum(len(t) for t in texts)
-        toks, mask = self.tokenizer.encode_batch(list(texts), self.max_len)
-        b = toks.shape[0]
-        parts = []
-        for s in range(0, b, self.MAX_BATCH):
-            t, m = toks[s:s + self.MAX_BATCH], mask[s:s + self.MAX_BATCH]
-            n = t.shape[0]
-            bucket = 1 << max(0, (n - 1).bit_length())
-            if bucket > n:               # pad rows; padded rows sliced off
-                pad = ((0, bucket - n), (0, 0))
-                t = np.pad(t, pad)
-                m = np.pad(m, pad)
-                m[n:, 0] = 1             # keep padded rows mask-valid
-            parts.append((n, self._jit_encode(self.params, t, m)))
+        with span("embed.tokenize", rows=len(texts)):
+            toks, mask = self.tokenizer.encode_batch(list(texts),
+                                                     self.max_len)
+            parts = [_pad_rows(toks[s:s + self.MAX_BATCH],
+                               mask[s:s + self.MAX_BATCH])
+                     for s in range(0, toks.shape[0], self.MAX_BATCH)]
         if not parts:
             return np.zeros((0, self.dim), np.float32)
-        return np.concatenate([np.asarray(o)[:n] for n, o in parts])
+        with span("embed.encode", bucket=len(parts[0][1])):
+            outs = [(n, self._jit_encode(self.params, t, m))
+                    for n, t, m in parts]
+            return np.concatenate([np.asarray(o)[:n] for n, o in outs])
 
     __call__ = embed
+
+
+def _pad_rows(t: np.ndarray, m: np.ndarray):
+    """(rows, tokens, mask) with the rows padded to the next power of two;
+    padded rows keep one valid mask position and are sliced off."""
+    n = t.shape[0]
+    bucket = 1 << max(0, (n - 1).bit_length())
+    if bucket > n:
+        pad = ((0, bucket - n), (0, 0))
+        t, m = np.pad(t, pad), np.pad(m, pad)
+        m[n:, 0] = 1
+    return n, t, m

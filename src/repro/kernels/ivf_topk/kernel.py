@@ -163,6 +163,7 @@ def topk_ip_pallas(embs, queries, k: int, *, block_n: int = 512,
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
         interpret=interpret,
+        name="ivf_topk",
     )(valid, embs, queries)
     if q_pad:
         out_v, out_i = out_v[:q], out_i[:q]

@@ -245,6 +245,7 @@ def slab_topk_pallas(emb, queries, virt, k: int, scales=None, luts=None, *,
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
         interpret=interpret,
+        name="slab_topk",
     )(*operands)
     if q_pad:
         out_v, out_r = out_v[:nq], out_r[:nq]

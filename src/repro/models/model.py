@@ -174,31 +174,39 @@ def apply_block(kind: str, p, x, cfg: ModelConfig, *, positions, causal,
     """Returns (x_out, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in ATTN_KINDS:
-        x, new_cache = _attention_sub_block(
-            p, x, cfg, kind, positions=positions, causal=causal, mode=mode,
-            cache=cache, cache_len=cache_len, window_mode=window_mode,
-            attn_impl=attn_impl, dist=dist)
-        h = rms_norm(x, p["norm2"], cfg.norm_eps)
-        if kind in ("moe", "swa_moe"):
-            # decode is dropless: capacity = T covers the all-to-one worst case
-            cap = x.shape[0] * x.shape[1] if mode == "decode" else 0
-            if dist is not None and dist.moe_impl == "ep":
-                if cfg.num_experts % dist.model_size == 0:
-                    from repro.models.distributed import moe_block_ep as _moe
+        with jax.named_scope("attention"):
+            x, new_cache = _attention_sub_block(
+                p, x, cfg, kind, positions=positions, causal=causal,
+                mode=mode, cache=cache, cache_len=cache_len,
+                window_mode=window_mode, attn_impl=attn_impl, dist=dist)
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, p["norm2"], cfg.norm_eps)
+            if kind in ("moe", "swa_moe"):
+                # decode is dropless: capacity = T covers the all-to-one
+                # worst case
+                cap = x.shape[0] * x.shape[1] if mode == "decode" else 0
+                if dist is not None and dist.moe_impl == "ep":
+                    if cfg.num_experts % dist.model_size == 0:
+                        from repro.models.distributed import (
+                            moe_block_ep as _moe)
+                    else:
+                        # non-divisible expert count: TP-experts
+                        # (ff-sharded)
+                        from repro.models.distributed import (
+                            moe_block_tp as _moe)
+                    y, aux = _moe(
+                        dist, p["moe"], h, num_experts=cfg.num_experts,
+                        top_k=cfg.num_experts_per_tok,
+                        capacity_factor=cfg.expert_capacity_factor,
+                        capacity=cap)
                 else:
-                    # non-divisible expert count: TP-experts (ff-sharded)
-                    from repro.models.distributed import moe_block_tp as _moe
-                y, aux = _moe(
-                    dist, p["moe"], h, num_experts=cfg.num_experts,
-                    top_k=cfg.num_experts_per_tok,
-                    capacity_factor=cfg.expert_capacity_factor, capacity=cap)
+                    y, aux = moe_block(
+                        p["moe"], h, num_experts=cfg.num_experts,
+                        top_k=cfg.num_experts_per_tok,
+                        capacity_factor=cfg.expert_capacity_factor,
+                        capacity=cap)
             else:
-                y, aux = moe_block(p["moe"], h, num_experts=cfg.num_experts,
-                                   top_k=cfg.num_experts_per_tok,
-                                   capacity_factor=cfg.expert_capacity_factor,
-                                   capacity=cap)
-        else:
-            y = mlp(p["mlp"], h)
+                y = mlp(p["mlp"], h)
         return x + y, new_cache, aux
     if kind == "mamba2":
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
@@ -299,10 +307,11 @@ def _default_positions(cfg: ModelConfig, b, s, offset=0):
 
 
 def _logits(params, cfg: ModelConfig, x):
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        return x @ params["embed"].astype(x.dtype).T
-    return x @ params["lm_head"].astype(x.dtype)
+    with jax.named_scope("output_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            return x @ params["embed"].astype(x.dtype).T
+        return x @ params["lm_head"].astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -389,12 +398,13 @@ def encode(params, cfg: ModelConfig, batch, *, compute_dtype=jnp.float32,
     x, _, _ = _run_stack(params, x, cfg, positions=positions, causal=False,
                          mode="train", caches=None, cache_len=0,
                          window_mode=False, attn_impl=attn_impl, remat=False)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    mask = batch.get("attn_mask")
-    if mask is None:
-        emb = x.mean(axis=1)
-    else:
-        m = mask.astype(x.dtype)[..., None]
-        emb = (x * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
-    emb = emb / jnp.linalg.norm(emb, axis=-1, keepdims=True).clip(1e-9)
-    return emb
+    with jax.named_scope("pooling"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        mask = batch.get("attn_mask")
+        if mask is None:
+            emb = x.mean(axis=1)
+        else:
+            m = mask.astype(x.dtype)[..., None]
+            emb = (x * m).sum(1) / jnp.maximum(m.sum(1), 1.0)
+        return emb / jnp.linalg.norm(emb, axis=-1,
+                                     keepdims=True).clip(1e-9)

@@ -15,6 +15,7 @@ This is the host-side orchestration layer that the decode_32k serve_step
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -50,6 +51,8 @@ class ContinuousBatcher:
         self.next_tok = np.zeros(num_slots, np.int32)
         self.slots = [SlotState() for _ in range(num_slots)]
         self.completed: Dict[int, List[int]] = {}
+        # perf_counter when each request's first token reached the host
+        self.first_token_at: Dict[int, float] = {}
 
         self._prefill1 = jax.jit(self._prefill_one)
         self._step = jax.jit(self._decode_all)
@@ -90,6 +93,7 @@ class ContinuousBatcher:
         last_logits, caches1 = self._prefill1(self.params, toks)
         self._copy_prefix_into_slot(slot, caches1, L)
         self.next_tok[slot] = int(jnp.argmax(last_logits[0]))
+        self.first_token_at[request_id] = time.perf_counter()
         self.slots[slot] = SlotState(request_id=request_id,
                                      budget=max_new_tokens)
         return slot

@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.core.costs import EdgeCostModel, LatencyBreakdown
 from repro.core.faults import DegradationPolicy
+from repro.core.tracing import span
 from repro.data.tokenizer import HashingTokenizer
 
 
@@ -53,7 +54,10 @@ class RAGResponse:
     retrieval: LatencyBreakdown
     prefill_edge_s: float
     ttft_edge_s: float
-    ttft_wall_s: float
+    ttft_wall_s: float               # wall seconds from the batch's start
+    #                                  (make_job) to this request's first
+    #                                  token on the host; to its prompt
+    #                                  being ready when nothing generates
     decode_wall_s: float = 0.0
     decode_edge_s: float = 0.0       # modeled decode ticks for the batch
     prefetch_saved_s: float = 0.0    # edge seconds hidden by prefetch overlap
@@ -101,7 +105,12 @@ class BatchJob:
     prefill_edge: Optional[List[float]] = None
     out_tokens: Optional[List[List[int]]] = None
     decode_wall: float = 0.0
-    retrieval_wall: float = 0.0
+    retrieval_wall: float = 0.0             # S1-S3 spans' wall seconds
+    started: float = dataclasses.field(default_factory=time.perf_counter)
+    first_token_at: Optional[List[float]] = None   # per query: when its
+    #                                         first token reached the host
+    #                                         (its prompt was ready, until
+    #                                         S4 generates one)
     maintenance_s: float = 0.0
     queue_wait_s: float = 0.0               # set by the pipeline at S1 fire
     replans: int = 0                        # stale-plan S1 re-entries
@@ -135,6 +144,7 @@ class RAGEngine:
         # scheduler hook or the staged pipeline owns draining and the
         # engine never touches the queue.  Exactly one component drains.
         self.maintenance_owner = maintenance_owner
+        self.batches = 0                  # answer_batch calls: request ids
 
     def answer_batch(self, queries: Sequence[str], query_embs: np.ndarray,
                      get_chunks: Optional[Callable[[Sequence[int]],
@@ -175,23 +185,28 @@ class RAGEngine:
         """
         if not len(queries):
             return []
-        job = self.make_job(queries, query_embs, get_chunks,
-                            deadlines=deadlines, policy=policy,
-                            prefetch=prefetch, tenants=tenants)
-        self.stage_plan(job)
-        self.stage_fetch(job)
-        self.stage_score(job)
-        self.stage_decode(job, batcher=batcher)
-        # deferred index maintenance drains AFTER decode — split / merge /
-        # restore work queued by online inserts/removes runs between serving
-        # steps instead of inside a query's TTFT window.  Only when the
-        # engine OWNS draining: with maintenance_owner="external" a
-        # scheduler hook / the staged pipeline drains instead (never both).
-        sched = getattr(self.index, "maintenance", None)
-        if (self.maintenance_owner == "engine" and sched is not None
-                and len(sched)):
-            job.maintenance_s = sched.drain(self.maintenance_budget_s).edge_s
-        return self.finalize(job)
+        with span("rag.answer_batch", batch=self.batches,
+                  queries=len(queries)):
+            self.batches += 1
+            job = self.make_job(queries, query_embs, get_chunks,
+                                deadlines=deadlines, policy=policy,
+                                prefetch=prefetch, tenants=tenants)
+            self.stage_plan(job)
+            self.stage_fetch(job)
+            self.stage_score(job)
+            self.stage_decode(job, batcher=batcher)
+            # deferred index maintenance drains AFTER decode — split /
+            # merge / restore work queued by online inserts/removes runs
+            # between serving steps instead of inside a query's TTFT
+            # window.  Only when the engine OWNS draining: with
+            # maintenance_owner="external" a scheduler hook / the staged
+            # pipeline drains instead (never both).
+            sched = getattr(self.index, "maintenance", None)
+            if (self.maintenance_owner == "engine" and sched is not None
+                    and len(sched)):
+                job.maintenance_s = sched.drain(
+                    self.maintenance_budget_s).edge_s
+            return self.finalize(job)
 
     # ------------------------------------------------------------------
     # the staged path: make_job + stage_plan/fetch/score/decode + finalize
@@ -233,36 +248,37 @@ class RAGEngine:
         probe trimming under the job's (queue-wait-adjusted) deadlines.
         Service time: per-query embed charges + ONE fused centroid search
         (it runs once per batch, not once per query)."""
-        t0 = time.perf_counter()
-        kw = {}
-        retrieval_deadlines = None
-        if job.deadlines is not None:
-            retrieval_deadlines = [
-                None if d is None
-                else d * (1.0 - job.policy.prefill_reserve_frac)
-                for d in job.deadlines]
-            kw["deadlines"] = retrieval_deadlines
-            kw["policy"] = job.policy
-        if job.tenants is not None:
-            # TenantRouter path: the router plans per tenant (handling
-            # prefetch internally) and merges into one cross-tenant plan
-            job.state = self.index.search_begin(
-                job.query_embs, self.k, self.nprobe,
-                query_chars=[len(q) for q in job.queries],
-                tenants=job.tenants, deadlines=retrieval_deadlines,
-                policy=job.policy, prefetch=job.prefetch)
-        else:
-            if job.prefetch:
-                kw["plan"] = self.index.plan_batch(
-                    job.query_embs, self.nprobe, prefetch_storage=True,
-                    deadlines=retrieval_deadlines, policy=job.policy,
-                    query_chars=[len(q) for q in job.queries])
-                kw.pop("deadlines", None)    # the plan carries them already
-                kw.pop("policy", None)
-            job.state = self.index.search_begin(
-                job.query_embs, self.k, self.nprobe,
-                query_chars=[len(q) for q in job.queries], **kw)
-        job.retrieval_wall += time.perf_counter() - t0
+        with span("s1.stage") as t:
+            kw = {}
+            retrieval_deadlines = None
+            if job.deadlines is not None:
+                retrieval_deadlines = [
+                    None if d is None
+                    else d * (1.0 - job.policy.prefill_reserve_frac)
+                    for d in job.deadlines]
+                kw["deadlines"] = retrieval_deadlines
+                kw["policy"] = job.policy
+            if job.tenants is not None:
+                # TenantRouter path: the router plans per tenant (handling
+                # prefetch internally) and merges into one cross-tenant plan
+                job.state = self.index.search_begin(
+                    job.query_embs, self.k, self.nprobe,
+                    query_chars=[len(q) for q in job.queries],
+                    tenants=job.tenants, deadlines=retrieval_deadlines,
+                    policy=job.policy, prefetch=job.prefetch)
+            else:
+                if job.prefetch:
+                    kw["plan"] = self.index.plan_batch(
+                        job.query_embs, self.nprobe, prefetch_storage=True,
+                        deadlines=retrieval_deadlines, policy=job.policy,
+                        query_chars=[len(q) for q in job.queries])
+                    # the plan carries the deadlines already
+                    kw.pop("deadlines", None)
+                    kw.pop("policy", None)
+                job.state = self.index.search_begin(
+                    job.query_embs, self.k, self.nprobe,
+                    query_chars=[len(q) for q in job.queries], **kw)
+        job.retrieval_wall += t.elapsed
         lats = job.state.lats
         # one fused centroid launch per index in the batch: one for a
         # standalone index, one PER TENANT through a router
@@ -280,10 +296,10 @@ class RAGEngine:
         shrinks the plan's remaining retrieval budgets so the ladder sees
         queue wait, not just execution time.  Service time: the owner
         charges (each unique cluster is resolved exactly once)."""
-        t0 = time.perf_counter()
-        job.state.shrink_deadlines(extra_wait_s)
-        self.index.search_fetch(job.state)
-        job.retrieval_wall += time.perf_counter() - t0
+        with span("s2.stage") as t:
+            job.state.shrink_deadlines(extra_wait_s)
+            self.index.search_fetch(job.state)
+        job.retrieval_wall += t.elapsed
         job.stage_edge_s["s2"] = sum(lat.stage_s("fetch")
                                      for lat in job.state.lats)
         return job
@@ -292,22 +308,26 @@ class RAGEngine:
         """S3 — slab pack + multi-query top-k scoring, then context fetch
         and prompt assembly.  Service time: the score-group charges (pack
         copies, fused dequant, shared-hit DRAM re-reads, fused top-k)."""
-        t0 = time.perf_counter()
-        job.ids, _, job.lats = self.index.search_finish(job.state)
-        nq = job.nq
-        job.id_lists = [[int(i) for i in job.ids[qi] if i >= 0]
-                        for qi in range(nq)]
-        if job.tenants is not None:
-            job.contexts = [self.index.get_chunks(t, idl)
-                            for t, idl in zip(job.tenants, job.id_lists)]
-        else:
-            job.contexts = [job.get_chunks(idl) for idl in job.id_lists]
-        job.prompts = [" ".join(ctx + [q])
-                       for ctx, q in zip(job.contexts, job.queries)]
-        job.prefill_edge = [
-            self.cost.prefill_latency(max(1, len(p) // 3))
-            for p in job.prompts]
-        job.retrieval_wall += time.perf_counter() - t0
+        with span("s3.stage") as t:
+            job.ids, _, job.lats = self.index.search_finish(job.state)
+            nq = job.nq
+            job.id_lists = [[int(i) for i in job.ids[qi] if i >= 0]
+                            for qi in range(nq)]
+            with span("s3.prompt"):
+                if job.tenants is not None:
+                    job.contexts = [
+                        self.index.get_chunks(tenant, idl)
+                        for tenant, idl in zip(job.tenants, job.id_lists)]
+                else:
+                    job.contexts = [job.get_chunks(idl)
+                                    for idl in job.id_lists]
+                job.prompts = [" ".join(ctx + [q])
+                               for ctx, q in zip(job.contexts, job.queries)]
+            job.prefill_edge = [
+                self.cost.prefill_latency(max(1, len(p) // 3))
+                for p in job.prompts]
+        job.retrieval_wall += t.elapsed
+        job.first_token_at = [t.end] * nq
         job.stage_edge_s["s3"] = sum(lat.stage_s("score")
                                      for lat in job.lats)
         return job
@@ -325,21 +345,25 @@ class RAGEngine:
             tokenizer = (self.generator.tokenizer if self.generator
                          is not None else HashingTokenizer(
                              vocab_size=batcher.cfg.vocab_size))
-            t1 = time.perf_counter()
-            completed = batcher.run(
-                [{"id": qi,
-                  "prompt_tokens": tokenizer.encode(p, batcher.max_len),
-                  "max_new_tokens": self.max_new_tokens}
-                 for qi, p in enumerate(job.prompts)])
-            job.decode_wall = (time.perf_counter() - t1) / nq
+            with span("s4.batch_decode", queries=nq) as t:
+                completed = batcher.run(
+                    [{"id": qi,
+                      "prompt_tokens": tokenizer.encode(p, batcher.max_len),
+                      "max_new_tokens": self.max_new_tokens}
+                     for qi, p in enumerate(job.prompts)])
+            job.decode_wall = t.elapsed / nq
             for qi in range(nq):
                 job.out_tokens[qi] = completed.get(qi, [])
+                if qi in batcher.first_token_at:
+                    job.first_token_at[qi] = batcher.first_token_at[qi]
         elif self.generator is not None:
-            t1 = time.perf_counter()
             for qi, p in enumerate(job.prompts):
-                job.out_tokens[qi] = self.generator.generate(
-                    p, self.max_new_tokens)
-            job.decode_wall = (time.perf_counter() - t1) / nq
+                with span("s4.answer", query=qi) as t:
+                    job.out_tokens[qi] = self.generator.generate(
+                        p, self.max_new_tokens)
+                job.first_token_at[qi] = self.generator.first_token_at
+                job.decode_wall += t.elapsed
+            job.decode_wall /= nq
         job.stage_edge_s["s4"] = (
             sum(job.prefill_edge)
             + self.cost.decode_latency(self.max_new_tokens))
@@ -376,7 +400,7 @@ class RAGEngine:
                 context=job.contexts[qi], output_tokens=job.out_tokens[qi],
                 retrieval=lat, prefill_edge_s=prefill_edge,
                 ttft_edge_s=ttft_edge,
-                ttft_wall_s=job.retrieval_wall / nq,
+                ttft_wall_s=job.first_token_at[qi] - job.started,
                 decode_wall_s=job.decode_wall,
                 decode_edge_s=decode_edge,
                 prefetch_saved_s=saved,
@@ -424,34 +448,58 @@ class GeneratorModel:
             if reduced:
                 cfg = cfg.reduced(num_layers=2, d_model=256)
         self.cfg = cfg
+
+        # named programs: a profiler trace shows jit_generator_prefill and
+        # jit_generator_decode
+        def generator_init(key):
+            return init_params(cfg, key, dtype or jnp.float32)
+
+        def generator_prefill(params, batch, caches):
+            return prefill(params, cfg, batch, caches)
+
+        def generator_decode(params, tokens, caches, cache_len):
+            return decode_step(params, cfg, tokens, caches, cache_len)
+
         if params is None:
-            dtype = dtype or jnp.float32
-            params = jax.jit(lambda key: init_params(cfg, key, dtype))(
-                jax.random.PRNGKey(seed))
+            params = jax.jit(generator_init)(jax.random.PRNGKey(seed))
         self.params = params
         self.tokenizer = HashingTokenizer(vocab_size=cfg.vocab_size)
         self.max_prompt = max_prompt
-        self._prefill = jax.jit(
-            lambda p, b, c: prefill(p, self.cfg, b, c))
-        self._decode = jax.jit(
-            lambda p, t, c, n: decode_step(p, self.cfg, t, c, n))
+        self._prefill = jax.jit(generator_prefill)
+        self._decode = jax.jit(generator_decode)
         self._init_cache = init_cache
+        self.first_token_at = 0.0     # perf_counter of the last generate's
+        #                               first token on the host
 
     def generate(self, prompt: str, max_new_tokens: int = 16) -> List[int]:
+        """Greedy decoding of ``max_new_tokens`` tokens after a left-padded
+        ``max_prompt`` prefill.  Spans: ``s4.tokenize``, ``s4.kv_init``,
+        ``s4.prefill`` (its end, the first token on the host, is
+        ``first_token_at``) and one ``s4.decode_step`` per new token."""
         import jax.numpy as jnp
-        ids = self.tokenizer.encode(prompt, self.max_prompt)
-        pad = self.max_prompt - len(ids)
-        toks = jnp.asarray([[0] * pad + ids], jnp.int32)  # left-pad
-        caches = self._init_cache(self.cfg, 1, self.max_prompt
-                                  + max_new_tokens)
-        logits, caches = self._prefill(self.params, {"tokens": toks}, caches)
-        out = []
-        cache_len = self.max_prompt
-        tok = logits.argmax(-1).astype(jnp.int32)[:, None]
-        for _ in range(max_new_tokens):
-            out.append(int(tok[0, 0]))
-            logits, caches = self._decode(self.params, tok, caches,
-                                          cache_len)
+        with span("s4.tokenize") as t:
+            ids = self.tokenizer.encode(prompt, self.max_prompt)
+            pad = self.max_prompt - len(ids)
+            toks = jnp.asarray([[0] * pad + ids], jnp.int32)  # left-pad
+            t.note(tokens=len(ids))
+        with span("s4.kv_init"):
+            caches = self._init_cache(self.cfg, 1, self.max_prompt
+                                      + max_new_tokens)
+        with span("s4.prefill") as t:
+            logits, caches = self._prefill(self.params, {"tokens": toks},
+                                           caches)
             tok = logits.argmax(-1).astype(jnp.int32)[:, None]
+            out = [int(tok[0, 0])]
+        self.first_token_at = t.end
+        cache_len = self.max_prompt
+        for step in range(max_new_tokens):
+            with span("s4.decode_step", step=step):
+                logits, caches = self._decode(self.params, tok, caches,
+                                              cache_len)
+                tok = logits.argmax(-1).astype(jnp.int32)[:, None]
+                # the last step's token is never used or read back
+                # (PERF.md section 7)
+                if step + 1 < max_new_tokens:
+                    out.append(int(tok[0, 0]))
             cache_len += 1
-        return out
+        return out[:max_new_tokens]
