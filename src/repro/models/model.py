@@ -260,12 +260,6 @@ def _run_stack(params, x, cfg: ModelConfig, *, positions, causal, mode,
     # xs: stacked params per pattern position (None for shared slots)
     stacked = tuple(p for p in params["blocks"])
 
-    def body(x, xs):
-        gp, gc = xs
-        x, ncs, aux = group(x, gp, gc)
-        return x, (ncs, aux)
-
-    xs = (stacked, caches)
     if unroll_layers:
         # dry-run accounting mode: XLA's cost_analysis counts a while body
         # ONCE, so the roofline run unrolls the layer loop to get true
@@ -273,14 +267,30 @@ def _run_stack(params, x, cfg: ModelConfig, *, positions, causal, mode,
         aux_total = jnp.zeros((), jnp.float32)
         ys = []
         for r in range(cfg.depth_repeat):
-            xr = jax.tree.map(lambda a: a[r], xs)
-            x, (ncs, aux) = body(x, xr)
+            gp, gc = jax.tree.map(lambda a: a[r], (stacked, caches))
+            x, ncs, aux = group(x, gp, gc)
             ys.append(ncs)
             aux_total = aux_total + aux
         new_caches = jax.tree.map(lambda *a: jnp.stack(a), *ys)
         return x, new_caches, aux_total
-    x, (new_caches, auxs) = jax.lax.scan(body, x, xs,
-                                         length=cfg.depth_repeat)
+
+    # the caches ride in the scan's carry, so a program that is donated
+    # them updates their buffer in place; as scanned inputs and outputs
+    # they would be written to a second buffer
+    def body(carry, gp):
+        x, cs, i = carry
+        gc = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), cs)
+        x, ncs, aux = group(x, gp, gc)
+        if cs is not None:
+            cs = jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
+                cs, ncs)
+        return (x, cs, i + 1), aux
+
+    (x, new_caches, _), auxs = jax.lax.scan(
+        body, (x, caches, jnp.zeros((), jnp.int32)), stacked,
+        length=cfg.depth_repeat)
     return x, new_caches, jnp.sum(auxs)
 
 

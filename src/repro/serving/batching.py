@@ -55,7 +55,8 @@ class ContinuousBatcher:
         self.first_token_at: Dict[int, float] = {}
 
         self._prefill1 = jax.jit(self._prefill_one)
-        self._step = jax.jit(self._decode_all)
+        # self.caches is replaced every tick, so the step updates it in place
+        self._step = jax.jit(self._decode_all, donate_argnums=1)
 
     # ---- jitted kernels -------------------------------------------------
     def _prefill_one(self, params, tokens):

@@ -465,17 +465,24 @@ class GeneratorModel:
         self.params = params
         self.tokenizer = HashingTokenizer(vocab_size=cfg.vocab_size)
         self.max_prompt = max_prompt
-        self._prefill = jax.jit(generator_prefill)
-        self._decode = jax.jit(generator_decode)
+        # the KV cache is donated: each program updates it in place, so
+        # the decode steps in flight share one buffer
+        self._prefill = jax.jit(generator_prefill, donate_argnums=2)
+        self._decode = jax.jit(generator_decode, donate_argnums=2)
         self._init_cache = init_cache
         self.first_token_at = 0.0     # perf_counter of the last generate's
         #                               first token on the host
 
     def generate(self, prompt: str, max_new_tokens: int = 16) -> List[int]:
         """Greedy decoding of ``max_new_tokens`` tokens after a left-padded
-        ``max_prompt`` prefill.  Spans: ``s4.tokenize``, ``s4.kv_init``,
-        ``s4.prefill`` (its end, the first token on the host, is
-        ``first_token_at``) and one ``s4.decode_step`` per new token."""
+        ``max_prompt`` prefill.  The prefill gives the first token, read to
+        the host where ``s4.prefill`` ends (``first_token_at``); the other
+        ``max_new_tokens - 1`` come from as many decode steps, dispatched
+        back to back by :meth:`decode_ahead` and read to the host in one
+        transfer (``s4.read_tokens``).  Spans: ``s4.tokenize``,
+        ``s4.kv_init``, ``s4.prefill`` and ``s4.decode`` (``steps``), which
+        holds one ``s4.decode_step`` (``step``, the dispatch alone) per
+        step and ``s4.read_tokens``."""
         import jax.numpy as jnp
         with span("s4.tokenize") as t:
             ids = self.tokenizer.encode(prompt, self.max_prompt)
@@ -491,15 +498,27 @@ class GeneratorModel:
             tok = logits.argmax(-1).astype(jnp.int32)[:, None]
             out = [int(tok[0, 0])]
         self.first_token_at = t.end
-        cache_len = self.max_prompt
-        for step in range(max_new_tokens):
+        steps = max(max_new_tokens - 1, 0)
+        with span("s4.decode", steps=steps):
+            rest = self.decode_ahead(tok, caches, steps)
+            with span("s4.read_tokens"):
+                if rest:
+                    out += np.asarray(jnp.concatenate(rest, 1))[0].tolist()
+        return out[:max_new_tokens]
+
+    def decode_ahead(self, tok, caches, steps: int) -> list:
+        """Dispatch ``steps`` greedy decode steps after a ``max_prompt``
+        prefill whose token is ``tok`` (``(1, 1)`` int32 on the device) and
+        whose cache is ``caches`` (donated).  Each step's argmax feeds the
+        next on the device and nothing is read to the host, so the host
+        runs ahead of the device.  Returns the steps' device tokens."""
+        import jax.numpy as jnp
+        cache_len, out = self.max_prompt, []
+        for step in range(steps):
             with span("s4.decode_step", step=step):
                 logits, caches = self._decode(self.params, tok, caches,
                                               cache_len)
                 tok = logits.argmax(-1).astype(jnp.int32)[:, None]
-                # the last step's token is never used or read back
-                # (PERF.md section 7)
-                if step + 1 < max_new_tokens:
-                    out.append(int(tok[0, 0]))
+            out.append(tok)
             cache_len += 1
-        return out[:max_new_tokens]
+        return out

@@ -14,7 +14,7 @@ SERVED_SPANS = ("embed.tokenize", "embed.encode", "rag.answer_batch",
                 "s2.stage", "s2.resolve", "s2.storage_read", "s2.regen",
                 "s3.stage", "s3.finish", "s3.slab_kernel", "s3.alg3",
                 "s3.prompt", "s4.answer", "s4.tokenize", "s4.kv_init",
-                "s4.prefill", "s4.decode_step")
+                "s4.prefill", "s4.decode", "s4.decode_step", "s4.read_tokens")
 MAX_NEW_TOKENS = 3
 
 
@@ -140,8 +140,14 @@ def test_a_served_batch_records_every_span(served):
                for r in embeds)
     for qi, resp in enumerate(out):
         mine = [r for r in recs if r.request == (root.request[0], qi)]
+        # the prefill gives the first token, a decode step each of the rest
+        (decode,) = [r for r in mine if r.name == "s4.decode"]
+        assert decode.attrs == {"steps": MAX_NEW_TOKENS - 1}
         steps = [r for r in mine if r.name == "s4.decode_step"]
-        assert [r.attrs["step"] for r in steps] == list(range(MAX_NEW_TOKENS))
+        assert [r.attrs["step"] for r in steps] == list(
+            range(MAX_NEW_TOKENS - 1))
+        (read,) = [r for r in mine if r.name == "s4.read_tokens"]
+        assert all(recs[r.parent] is decode for r in steps + [read])
         assert all(any(a is root for a in _ancestors(recs, r))
                    for r in steps)
         (tok,) = [r for r in mine if r.name == "s4.tokenize"]
@@ -183,7 +189,7 @@ def test_what_the_benchmark_wraps_is_still_there(served):
         tokens = gen.generate("a short prompt", MAX_NEW_TOKENS)
     finally:
         gen._prefill, gen._decode = prefill, decode
-    assert calls == ["prefill"] + ["decode"] * MAX_NEW_TOKENS
+    assert calls == ["prefill"] + ["decode"] * (MAX_NEW_TOKENS - 1)
     assert tokens == gen.generate("a short prompt", MAX_NEW_TOKENS)
 
 
