@@ -120,11 +120,12 @@ class Cell:
     slab_work: List[tuple] = dataclasses.field(default_factory=list)
     regen_tokens: List[tuple] = dataclasses.field(default_factory=list)
     top_logits: List[list] = dataclasses.field(default_factory=list)
+    thresholds: List[float] = dataclasses.field(default_factory=list)
 
     def clear_records(self):
         for recorded in (self.probes, self.slabs, self.scores,
-                         self.slab_work,
-                         self.regen_tokens, self.top_logits):
+                         self.slab_work, self.regen_tokens, self.top_logits,
+                         self.thresholds):
             recorded.clear()
         self.spans.by_name.clear()
 
@@ -159,14 +160,15 @@ def build(config: dict, seed: int, n_queries: int) -> Cell:
     k_enc, k_gen = seed_ints(seed, 2)
     with _timed(setup, "corpus_s"):
         corpus = make_corpus(config, n_queries, seed)
+    enc_arch, gen_arch = models.arch(enc_m), models.arch(gen_m)
     with _timed(setup, "weights_s"):
         topics = [topic_words(t) for t in range(config["topics"])]
-        enc_params = models.init_weights(
+        enc_params = enc_arch.init_weights(
             enc_m, k_enc, models.topic_rows(enc_m["vocab_size"], topics))
-        gen_params = models.init_weights(gen_m, k_gen)
+        gen_params = gen_arch.init_weights(gen_m, k_gen)
         jax.block_until_ready((enc_params, gen_params))
-    embedder = ModelEmbedder(models.model_config(enc_m), params=enc_params,
-                             max_len=enc_m["max_len"])
+    embedder = ModelEmbedder(enc_arch.program_config(enc_m),
+                             params=enc_params, max_len=enc_m["max_len"])
     with _timed(setup, "embed_corpus_s"):
         corpus_emb = embedder(corpus.texts)
     cost = EdgeCostModel()
@@ -179,7 +181,7 @@ def build(config: dict, seed: int, n_queries: int) -> Cell:
                     nlist=config["nlist"], seed=k_enc,
                     embeddings=corpus_emb)
     del corpus_emb
-    gen = GeneratorModel(models.model_config(gen_m), params=gen_params,
+    gen = GeneratorModel(gen_arch.program_config(gen_m), params=gen_params,
                          max_prompt=gen_m["max_prompt"])
     engine = RAGEngine(index, gen, cost_model=cost, k=config["k"],
                        nprobe=config["nprobe"],
@@ -193,8 +195,9 @@ def instrument(cell: Cell):
     """Spans around S1-S4 and the regeneration encoder, and the counts the
     per-layer readers and the correctness check take from the window: the
     probe's inputs and choices, each batch's packed slab (the slab top-k's
-    inputs) and scores, and the largest logit of each generation step (its
-    served token's logit, kept on the device until the window closes)."""
+    inputs) and scores, the Alg. 3 threshold after each batch, and the
+    largest logit of each generation step (its served token's logit, kept
+    on the device until the window closes)."""
     spans, engine, index = cell.spans, cell.engine, cell.index
 
     def probed(job, *_):
@@ -210,6 +213,7 @@ def instrument(cell: Cell):
 
     def scored(out, *_):
         cell.scores.append((np.array(out[0]), np.array(out[1])))
+        cell.thresholds.append(index.threshold.threshold)
 
     wrap(engine, "stage_plan", spans, "s1.plan", probed)
     wrap(engine, "stage_fetch", spans, "s2.fetch")

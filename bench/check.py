@@ -1,9 +1,10 @@
 """How ``correct`` is decided: the window's answers against the reference.
 
 Run once the window has closed, the device memory peak has been read and
-the program's engine is freed.  The reference (``bench/models.py``) reads
-only what the benchmark made: the texts, the weights and the served
-answers.  Numbers compared, each against the limit of its configuration:
+the program's engine is freed.  The reference (each model's architecture
+in ``bench/archs``, found by ``bench.models.arch``) reads only what the
+benchmark made: the texts, the weights and the served answers.  Numbers
+compared, each against the limit of its configuration:
 
 ``failed``           requests due in the window that got no answer, fewer
                      than k passages, passages other than those the slab
@@ -200,20 +201,20 @@ def retrieval_readings(cell, served, control=None) -> Dict[str, float]:
     the program's (its readings, for setting limits)."""
     m = cell.config["encoder"]
     tok = Tokens(m["vocab_size"])
-    params = cell.enc_params
+    params, encode = cell.enc_params, models.arch(m).encode
     q_texts = [s.query for s in served]
-    q_ref = models.encode(params, m, *tok.rows(q_texts, m["max_len"]))
+    q_ref = encode(params, m, *tok.rows(q_texts, m["max_len"]))
     pids = sorted({int(c) for s in served for c in s.response.chunk_ids})
     col = {p: i for i, p in enumerate(pids)}
-    p_ref = models.encode(params, m, *tok.rows(
+    p_ref = encode(params, m, *tok.rows(
         cell.corpus.get_chunks(pids), m["max_len"]))
     if control is None:
         q_prog = np.stack([s.embedding for s in served]).astype(np.float64)
         scores = [np.asarray(s.score, np.float64) for s in served]
     else:
-        q_prog = models.encode(params, m, *tok.rows(q_texts, m["max_len"]),
-                               control=control)
-        p_ctl = models.encode(params, m, *tok.rows(
+        q_prog = encode(params, m, *tok.rows(q_texts, m["max_len"]),
+                        control=control)
+        p_ctl = encode(params, m, *tok.rows(
             cell.corpus.get_chunks(pids), m["max_len"]), control=control)
         scores = [p_ctl[[col[int(c)] for c in s.response.chunk_ids]]
                   @ q_prog[i] for i, s in enumerate(served)]
@@ -256,7 +257,7 @@ def logit_readings(cell, served, sample: Sequence[int],
     With ``control`` the control's first choice and its logit stand in for
     the served token and the program's logit."""
     m = cell.config["generator"]
-    tok = Tokens(m["vocab_size"])
+    tok, logits = Tokens(m["vocab_size"]), models.arch(m).logits
     max_prompt, n_new = m["max_prompt"], m["max_new_tokens"]
     error = gap = 0.0
     at = np.arange(n_new)
@@ -269,13 +270,12 @@ def logit_readings(cell, served, sample: Sequence[int],
         row = np.array([0] * (max_prompt - len(ids)) + ids + out[:-1],
                        np.int32)
         pos = np.arange(max_prompt - 1, max_prompt - 1 + n_new)
-        ref = models.logits(cell.gen_params, m, row, pos)
+        ref = logits(cell.gen_params, m, row, pos)
         if control is None:
             chosen = np.asarray(out)
             given = np.asarray(served[i].top_logits[:n_new], np.float64)
         else:
-            ctl = models.logits(cell.gen_params, m, row, pos,
-                                control=control)
+            ctl = logits(cell.gen_params, m, row, pos, control=control)
             chosen, given = ctl.argmax(-1), ctl.max(-1)
         error = max(error, float(np.abs(given - ref[at, chosen]).max()))
         gap = max(gap, float((ref.max(-1) - ref[at, chosen]).max()))

@@ -1,4 +1,5 @@
-"""Operations and bytes the algorithm needs, from shapes; the chip's peaks.
+"""Operations and bytes the kernels need, from shapes; the chip's peaks.
+(A model's FLOPs are its architecture's, in ``bench/archs``.)
 
 The counts are of the work the algorithm asks for, the same whatever
 implements it: padding, masks and layout copies are left out, so a change
@@ -46,33 +47,3 @@ def roofline_share(flops: float, nbytes: float, seconds: float,
     least = max(flops / peak["bf16_flops_per_s"],
                 nbytes / peak["hbm_bytes_per_s"])
     return 100.0 * least / seconds
-
-
-def non_embedding_params(m: dict) -> int:
-    """Parameters a token's forward pass multiplies by, per token: the
-    block stack (norms included) and the final norm; the output head is
-    counted apart, since only decoded positions use it."""
-    d, q = m["hidden_size"], m["num_heads"] * m["head_dim"]
-    kv, ff = m["num_kv_heads"] * m["head_dim"], m["intermediate_size"]
-    return m["num_layers"] * (d * (q + 2 * kv) + q * d + 2 * d + 3 * d * ff) + d
-
-
-def encoder_flops(m: dict, lengths: Sequence[int]) -> float:
-    """Forward FLOPs of the bidirectional encoder over rows of ``lengths``
-    real tokens: 2 x parameters per token plus attention (scores and
-    weighted sum, 2 x 2 x ctx x q_dim per layer per token)."""
-    n, qd, L = non_embedding_params(m), m["num_heads"] * m["head_dim"], \
-        m["num_layers"]
-    return float(sum(2 * n * t + 4 * L * qd * t * t for t in lengths))
-
-
-def generator_flops(m: dict, prompt_len: int, new_tokens: int) -> float:
-    """Forward FLOPs of one causal generation: the prompt's real tokens,
-    then the ``new_tokens - 1`` generated tokens fed back; token i attends
-    to i + 1 positions; the output head runs once per generated token."""
-    n, qd, L = non_embedding_params(m), m["num_heads"] * m["head_dim"], \
-        m["num_layers"]
-    total = prompt_len + new_tokens - 1
-    attn = 4 * L * qd * total * (total + 1) / 2
-    head = 2 * m["hidden_size"] * m["vocab_size"] * new_tokens
-    return float(2 * n * total + attn + head)

@@ -239,6 +239,12 @@ def run(argv=None, *, chips_check=require_chips, spec_hook=None,
           f"{n_regen / len(served)}; stored clusters "
           f"{sum(c.stored for c in cell.index.clusters)} of "
           f"{cell.index.nlist}", flush=True)
+    thr = cell.thresholds or [0.0]
+    print(f"alg3 threshold s after each batch: quartiles "
+          f"{np.percentile(thr, [0, 25, 50, 75, 100]).tolist()} last "
+          f"{thr[-1]}; cache {len(cell.index.cache)} clusters, "
+          f"{cell.index.cache.total_bytes()} of "
+          f"{cell.index.cache.capacity_bytes} bytes", flush=True)
     print(f"compiles in window: {compiles['n']}; device memory peak "
           f"{mem_peak} bytes (limit {stats.get('bytes_limit')})", flush=True)
     print(f"gc in window: {len(pauses)} collections, "

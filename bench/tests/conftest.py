@@ -29,16 +29,15 @@ CPU_LIMITS = {"query_embed_gap": 1e-4, "probe_gap": 1e-6, "slab_gap": 1e-6,
 def small(cfg: dict, traffic: dict, rate: float = 2.0, layers: int = 2,
           width: int = 128, vocab: int = 512, own_limits: bool = False):
     """A configuration cut to CPU size: same shape of corpus and traffic,
-    ``layers``-deep ``width``-wide models; the mix offered at ``rate``;
-    the limits a sound CPU run meets, or with ``own_limits`` the
-    configuration's own."""
+    models cut by their architecture's ``small`` to ``layers`` deep and
+    ``width`` wide; the mix offered at ``rate``; the limits a sound CPU
+    run meets, or with ``own_limits`` the configuration's own."""
+    from bench import models
     cfg = dict(cfg, passages=1500, topics=77, nlist=46)
-    narrow = dict(num_layers=layers, hidden_size=width,
-                  num_heads=width // 64, num_kv_heads=width // 64,
-                  head_dim=64, intermediate_size=2 * width, vocab_size=vocab)
-    cfg["encoder"] = dict(cfg["encoder"], **narrow)
-    cfg["generator"] = dict(cfg["generator"], **narrow, max_prompt=256,
-                            max_new_tokens=4)
+    enc, gen = cfg["encoder"], cfg["generator"]
+    cfg["encoder"] = models.arch(enc).small(enc, layers, width, vocab)
+    cfg["generator"] = dict(models.arch(gen).small(gen, layers, width, vocab),
+                            max_prompt=256, max_new_tokens=4)
     if not own_limits:
         cfg["limits"] = dict(CPU_LIMITS)
     return cfg, dict(traffic, rate_per_s=rate)

@@ -1,10 +1,7 @@
 """Operations, bytes and model FLOPs from shapes; the peak table."""
-import json
-
 import pytest
 
 from bench import flops, models
-from conftest import ROOT
 
 
 def test_ivf_topk_counts_by_hand():
@@ -27,42 +24,16 @@ def test_roofline_share_takes_the_larger_bound():
     assert flops.roofline_share(10, 100, 20.0, peak) == pytest.approx(50.0)
 
 
-@pytest.mark.parametrize("name", sorted(
-    f.stem for f in (ROOT / "bench" / "configs").glob("*.json")))
-@pytest.mark.parametrize("model", ["encoder", "generator"])
-def test_param_count_matches_the_program(name, model):
-    m = json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
-    m = m[model]
-    assert models.param_count(m) == models.model_config(m).param_count()
-    # the generator is stablelm-2-1.6b's 1.64 B; gte-base about 137 M
-    expect = {"encoder": 136.8e6, "generator": 1.644e9}[model]
-    assert models.param_count(m) == pytest.approx(expect, rel=0.01)
-
-
-def test_init_weights_match_the_program_layout():
-    import jax
-    from repro.models import init_params
-    m = dict(json.loads((ROOT / "bench" / "configs" /
-                         "fiqa-gte-stablelm2.json").read_text())["generator"],
-             num_layers=2, hidden_size=64, num_heads=2, num_kv_heads=2,
-             head_dim=32, intermediate_size=96, vocab_size=300)
-    ours = models.init_weights(m, 3)
-    theirs = jax.eval_shape(lambda: init_params(models.model_config(m),
-                                                jax.random.PRNGKey(0)))
-    assert (jax.tree.structure(ours) == jax.tree.structure(theirs))
-    for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
-        assert a.shape == b.shape
-
-
 def test_model_flops_by_hand():
     m = {"num_layers": 2, "hidden_size": 4, "num_heads": 2, "head_dim": 2,
          "num_kv_heads": 2, "intermediate_size": 8, "vocab_size": 10}
-    n = flops.non_embedding_params(m)
+    dense = models.arch(m)
+    n = dense.non_embedding_params(m)
     assert n == 2 * (4 * 12 + 16 + 8 + 96) + 4
     # encoder: one row of 3 tokens
-    assert flops.encoder_flops(m, [3]) == 2 * n * 3 + 4 * 2 * 4 * 9
+    assert dense.encoder_flops(m, [3]) == 2 * n * 3 + 4 * 2 * 4 * 9
     # generator: 3 prompt tokens, 2 new: 4 tokens fed, head twice
-    assert flops.generator_flops(m, 3, 2) == (2 * n * 4 + 4 * 2 * 4 * 10
+    assert dense.generator_flops(m, 3, 2) == (2 * n * 4 + 4 * 2 * 4 * 10
                                               + 2 * 4 * 10 * 2)
 
 

@@ -9,7 +9,7 @@ import pytest
 from bench import run as runmod, trace
 
 READERS = ("embed_tokenize_ms", "embed_encode_ms", "s4_prep_ms",
-           "s4_prefill_ms", "s4_decode_step_ms", "first_token_ms")
+           "s4_prefill_ms", "first_token_ms")
 
 
 def request(t, regen=False, prefill=0.1):
@@ -48,7 +48,6 @@ def window(host):
     ("embed_encode_ms", 9.0),
     ("s4_prep_ms", 3.0),
     ("s4_prefill_ms", 100.0),
-    ("s4_decode_step_ms", 6.5),
     ("first_token_ms", 118.0),
 ])
 def test_reader_on_made_up_spans(name, expected):
