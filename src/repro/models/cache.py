@@ -22,27 +22,48 @@ from repro.configs.shapes import LONG_CONTEXT_WINDOW
 
 
 class KVCache(NamedTuple):
-    k: jax.Array          # (B, Smax, KH, D)
+    k: jax.Array          # (B, Smax, KH, D), or (R, B, Smax, KH, D) stacked
     v: jax.Array
 
-    def insert(self, k_new, v_new, cache_len, *, circular: bool):
-        """Insert (B, S_new, KH, D) at cache_len (mod size if ring buffer).
+    def insert(self, k_new, v_new, cache_len, *, circular: bool,
+               layer=None):
+        """Insert (..., B, S_new, KH, D) at cache_len (mod size if ring
+        buffer): rows of one layer's cache, or of every layer of a stack
+        (R, B, Smax, KH, D); with ``layer``, rows (B, S_new, KH, D) of
+        that layer of a stack.
 
         cache_len may be a scalar (synchronized batch) or a (B,) vector of
-        per-slot lengths (continuous batching, S_new must be 1)."""
-        smax = self.k.shape[1]
+        per-slot lengths (continuous batching, S_new must be 1, no
+        ``layer``)."""
+        pos = cache_len % self.k.shape[-3] if circular else cache_len
+
+        def put(buf, new):
+            new = new.astype(buf.dtype)
+            if jnp.ndim(pos) == 1:             # per-slot scatter, S_new == 1
+                rows = jnp.arange(buf.shape[-4])
+                return buf.at[..., rows, pos, :, :].set(new[..., 0, :, :])
+            start = (0,) * (buf.ndim - 3) + (pos, 0, 0)
+            if layer is not None:
+                new, start = new[None], (layer,) + start[1:]
+            return jax.lax.dynamic_update_slice(buf, new, start)
+        return KVCache(put(self.k, k_new), put(self.v, v_new))
+
+    def layer(self, i) -> "KVCache":
+        """Layer ``i`` of a stacked cache."""
+        return KVCache(*(jax.lax.dynamic_index_in_dim(a, i, 0, False)
+                         for a in self))
+
+    def with_row(self, k_new, v_new, cache_len, *, circular: bool):
+        """The cache as :meth:`insert` leaves it for one new token
+        (B, 1, KH, D), selected row by row instead of written: a reader
+        fuses the select and reads the cache where it lies."""
+        smax = self.k.shape[-3]
         pos = cache_len % smax if circular else cache_len
-        if jnp.ndim(pos) == 1:                 # per-slot scatter, S_new == 1
-            b = self.k.shape[0]
-            rows = jnp.arange(b)
-            k = self.k.at[rows, pos].set(k_new[:, 0].astype(self.k.dtype))
-            v = self.v.at[rows, pos].set(v_new[:, 0].astype(self.v.dtype))
-            return KVCache(k, v)
-        k = jax.lax.dynamic_update_slice_in_dim(
-            self.k, k_new.astype(self.k.dtype), pos, 1)
-        v = jax.lax.dynamic_update_slice_in_dim(
-            self.v, v_new.astype(self.v.dtype), pos, 1)
-        return KVCache(k, v)
+        if jnp.ndim(pos) == 0:                 # where insert's slice clamps
+            pos = jnp.minimum(pos, smax - 1)
+        at = (jnp.arange(smax) == jnp.reshape(pos, (-1, 1)))[..., None, None]
+        return KVCache(*(jnp.where(at, new.astype(a.dtype), a)
+                         for a, new in ((self.k, k_new), (self.v, v_new))))
 
 
 def kv_cache_spec(cfg: ModelConfig, kind: str, max_len: int,

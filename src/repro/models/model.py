@@ -101,7 +101,12 @@ def param_count(params) -> int:
 # ---------------------------------------------------------------------------
 def _attention_sub_block(p, x, cfg: ModelConfig, kind: str, *, positions,
                          causal, mode, cache: Optional[KVCache], cache_len,
-                         window_mode: bool, attn_impl: str, dist=None):
+                         window_mode: bool, attn_impl: str, dist=None,
+                         layer=None):
+    """Returns (x_out, new_cache).  With ``layer``, ``cache`` is the stack
+    the layer scan carries, decode attention reads that layer where it
+    lies, and the second result is the rows to insert (K/V after rotary),
+    which :func:`_run_stack` writes into the stack."""
     b, s, _ = x.shape
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     q = (h @ p["wq"].astype(x.dtype)).reshape(b, s, cfg.num_heads,
@@ -119,42 +124,44 @@ def _attention_sub_block(p, x, cfg: ModelConfig, kind: str, *, positions,
 
     window = cfg.sliding_window if kind in ("swa", "swa_moe") else 0
     new_cache = cache
+    if cache is not None:
+        size = cache.k.shape[-3]
+        # window_mode rings every attention layer (DESIGN.md §4)
+        _, circular = kv_cache_spec(cfg, kind, size, window_mode=window_mode)
     if mode == "decode":
         assert cache is not None and s == 1
-        _, circular = kv_cache_spec(cfg, kind, cache.k.shape[1],
-                                    window_mode=window_mode)
-        # window_mode rings every attention layer (DESIGN.md §4)
-        circular = circular or window_mode
+        own = cache if layer is None else cache.layer(layer)
         if dist is not None and dist.decode_attn_impl == "sharded":
             from repro.models.distributed import decode_attention_sharded
             out, nk, nv = decode_attention_sharded(
-                dist, q, cache.k, cache.v, k, v, cache_len,
+                dist, q, own.k, own.v, k, v, cache_len,
                 circular=circular, window=window,
                 logit_cap=cfg.attn_logit_softcap)
-            new_cache = KVCache(nk, nv)
+            new_cache = KVCache(nk, nv) if layer is None else KVCache(k, v)
         else:
-            new_cache = cache.insert(k, v, cache_len, circular=circular)
+            if layer is None:
+                own = new_cache = cache.insert(k, v, cache_len,
+                                               circular=circular)
+            else:
+                own = own.with_row(k, v, cache_len, circular=circular)
+                new_cache = KVCache(k, v)
             out = attn_lib.attend_decode(
-                q, new_cache.k, new_cache.v, jnp.asarray(cache_len) + 1,
+                q, own.k, own.v, jnp.asarray(cache_len) + 1,
                 window=window, logit_cap=cfg.attn_logit_softcap,
                 circular=circular)
     else:
         if mode == "prefill" and cache is not None:
-            _, circular = kv_cache_spec(cfg, kind, cache.k.shape[1],
-                                        window_mode=window_mode)
-            if circular:
-                # ring invariant: token p lives at slot p % size.  Scatter
-                # the last `size` tokens to their ring slots (static idx).
-                size = cache.k.shape[1]
-                if s <= size:
-                    new_cache = cache.insert(k, v, 0, circular=False)
-                else:
-                    pos = jnp.arange(s - size, s) % size
-                    new_cache = KVCache(
-                        cache.k.at[:, pos].set(k[:, -size:].astype(cache.k.dtype)),
-                        cache.v.at[:, pos].set(v[:, -size:].astype(cache.v.dtype)))
-            else:
-                new_cache = cache.insert(k, v, cache_len, circular=False)
+            # rows go in at cache_len (0), mod size for a ring buffer,
+            # where token p lives at slot p % size: the last `size` tokens
+            # rolled to their slots (a static shift)
+            new_cache = KVCache(k, v)
+            if circular and s > size:
+                new_cache = KVCache(*(jnp.roll(a[:, -size:],
+                                               (s - size) % size, 1)
+                                      for a in (k, v)))
+            if layer is None:
+                new_cache = cache.insert(*new_cache, cache_len,
+                                         circular=circular)
         use_chunked = (attn_impl == "chunked"
                        or (attn_impl == "auto" and s >= CHUNKED_ATTN_MIN_SEQ))
         if use_chunked:
@@ -170,15 +177,18 @@ def _attention_sub_block(p, x, cfg: ModelConfig, kind: str, *, positions,
 
 
 def apply_block(kind: str, p, x, cfg: ModelConfig, *, positions, causal,
-                mode, cache, cache_len, window_mode, attn_impl, dist=None):
-    """Returns (x_out, new_cache, aux_loss)."""
+                mode, cache, cache_len, window_mode, attn_impl, dist=None,
+                layer=None):
+    """Returns (x_out, new_cache, aux_loss).  ``layer``: see
+    :func:`_attention_sub_block` (attention kinds only)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in ATTN_KINDS:
         with jax.named_scope("attention"):
             x, new_cache = _attention_sub_block(
                 p, x, cfg, kind, positions=positions, causal=causal,
                 mode=mode, cache=cache, cache_len=cache_len,
-                window_mode=window_mode, attn_impl=attn_impl, dist=dist)
+                window_mode=window_mode, attn_impl=attn_impl, dist=dist,
+                layer=layer)
         with jax.named_scope("mlp"):
             h = rms_norm(x, p["norm2"], cfg.norm_eps)
             if kind in ("moe", "swa_moe"):
@@ -238,7 +248,9 @@ def _run_stack(params, x, cfg: ModelConfig, *, positions, causal, mode,
         sp_sharding = NamedSharding(
             dist.mesh, P(dist.data_axes, dist.model_axis, None))
 
-    def group(x, group_params, group_caches):
+    def group(x, group_params, group_caches, layer=None):
+        """``layer``: the scan's index, with the attention caches of
+        ``group_caches`` stacked (the others are that layer's)."""
         new_caches = []
         aux_total = jnp.zeros((), jnp.float32)
         for i, kind in enumerate(pattern):
@@ -247,7 +259,8 @@ def _run_stack(params, x, cfg: ModelConfig, *, positions, causal, mode,
             x, nc, aux = apply_block(
                 kind, p, x, cfg, positions=positions, causal=causal,
                 mode=mode, cache=c, cache_len=cache_len,
-                window_mode=window_mode, attn_impl=attn_impl, dist=dist)
+                window_mode=window_mode, attn_impl=attn_impl, dist=dist,
+                layer=layer if kind in ATTN_KINDS else None)
             if sp_sharding is not None:
                 x = jax.lax.with_sharding_constraint(x, sp_sharding)
             new_caches.append(nc)
@@ -276,21 +289,45 @@ def _run_stack(params, x, cfg: ModelConfig, *, positions, causal, mode,
 
     # the caches ride in the scan's carry, so a program that is donated
     # them updates their buffer in place; as scanned inputs and outputs
-    # they would be written to a second buffer
+    # they would be written to a second buffer.  The small SSM states are
+    # sliced out and written back whole.  A KV cache is never copied: its
+    # layer is read where it lies, the prompt's rows go into it at their
+    # static position, and a decode step's rows leave the scan and go into
+    # every layer in one update after it (on a TPU, a row written into the
+    # carry at a traced position moves the whole stack to another layout)
+    kv = [kind in ATTN_KINDS for kind in pattern]
+
+    def ring(kind, c):
+        return kv_cache_spec(cfg, kind, c.k.shape[-3],
+                             window_mode=window_mode)[1]
+
     def body(carry, gp):
         x, cs, i = carry
-        gc = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), cs)
-        x, ncs, aux = group(x, gp, gc)
-        if cs is not None:
-            cs = jax.tree.map(
-                lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, i, 0),
-                cs, ncs)
-        return (x, cs, i + 1), aux
+        if cs is None:
+            x, _, aux = group(x, gp, None, i)
+            return (x, cs, i + 1), (aux, None)
+        x, ncs, aux = group(x, gp, tuple(
+            c if is_kv else jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False), c)
+            for is_kv, c in zip(kv, cs)), i)
+        new, rows = [], []
+        for kind, is_kv, c, n in zip(pattern, kv, cs, ncs):
+            if not is_kv:
+                c = jax.tree.map(lambda a, b: (
+                    jax.lax.dynamic_update_index_in_dim(a, b, i, 0)), c, n)
+            elif mode == "prefill":
+                c = c.insert(*n, cache_len, circular=ring(kind, c), layer=i)
+            new.append(c)
+            rows.append(n if is_kv and mode == "decode" else None)
+        return (x, tuple(new), i + 1), (aux, tuple(rows))
 
-    (x, new_caches, _), auxs = jax.lax.scan(
+    (x, new_caches, _), (auxs, rows) = jax.lax.scan(
         body, (x, caches, jnp.zeros((), jnp.int32)), stacked,
         length=cfg.depth_repeat)
+    if mode == "decode":
+        new_caches = tuple(
+            c if r is None else c.insert(*r, cache_len, circular=ring(kind, c))
+            for kind, c, r in zip(pattern, new_caches, rows))
     return x, new_caches, jnp.sum(auxs)
 
 

@@ -127,19 +127,32 @@ def test_the_cache_passed_in_is_donated(gen):
     assert not any(a.is_deleted() for a in jax.tree.leaves(after))
 
 
-@pytest.mark.parametrize("arch", ["stablelm-1.6b", "gemma3-12b",
-                                  "zamba2-2.7b", "rwkv6-1.6b"])
-def test_donated_caches_match_the_unrolled_layers(arch):
+@pytest.mark.parametrize("arch,window,slots", [
+    pytest.param("stablelm-1.6b", 0, None, id="stablelm-1.6b"),
+    pytest.param("gemma3-12b", 0, None, id="gemma3-12b"),
+    pytest.param("zamba2-2.7b", 0, None, id="zamba2-2.7b"),
+    pytest.param("rwkv6-1.6b", 0, None, id="rwkv6-1.6b"),
+    # ring buffers of 16 slots that the 24-token prompt has wrapped
+    pytest.param("gemma3-12b", 16, None, id="gemma3-12b-ring-wrapped"),
+    # per-slot lengths, as ContinuousBatcher._decode_all passes them
+    pytest.param("stablelm-1.6b", 0, (24, 13, 5), id="stablelm-1.6b-per-slot"),
+])
+def test_donated_caches_match_the_unrolled_layers(arch, window, slots):
     """Attention, ring-buffer, SSM and RWKV caches: a prefill and two
     decode steps whose programs are donated the caches, which the layer
     scan carries and updates in place, give the same logits and caches
     bit for bit as the layers unrolled one by one without donation."""
+    import dataclasses
     from repro import configs
     from repro.models import forward, init_cache, init_params
     cfg = configs.get_config(arch).reduced()
+    if window:
+        cfg = dataclasses.replace(cfg, sliding_window=window)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    toks = jax.random.randint(jax.random.PRNGKey(1), (1, 24), 0,
+    batch = 1 if slots is None else len(slots)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (batch, 24), 0,
                               cfg.vocab_size)
+    lens = 24 if slots is None else jnp.asarray(slots, jnp.int32)
 
     def run(scanned):
         def program(mode):
@@ -154,9 +167,9 @@ def test_donated_caches_match_the_unrolled_layers(arch):
             return jax.jit(step, donate_argnums=2 if scanned else ())
         pre, dec = program("prefill"), program("decode")
         logits, caches = pre(params, {"tokens": toks},
-                             init_cache(cfg, 1, 32))
+                             init_cache(cfg, batch, 32))
         out = [logits]
-        for n in (24, 25):
+        for n in (lens, lens + 1):
             tok = out[-1].argmax(-1).astype(jnp.int32)[:, None]
             logits, caches = dec(params, {"tokens": tok}, caches, n)
             out.append(logits)

@@ -7,11 +7,17 @@ asserts that the compiled program holds the Mosaic kernel
 (``tpu_custom_call``).  What interpret mode accepts and Mosaic refuses
 (gathers, integer argmin, dynamic-lane stores, fp16 loads) fails here.
 
+``test_decode_step_reads_the_kv_cache_in_place`` compiles the generator's
+decode step at stablelm-2-1.6b's widths and reads the compiled program.
+
 The topology is described inside a module fixture, never while a module is
 imported: only one process may load the TPU library, so under pytest-xdist
 only the worker that runs this file may touch it.
 """
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +25,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from repro import configs
 from repro.kernels.ivf_topk.kernel import topk_ip_pallas
 from repro.kernels.slab_topk.kernel import slab_topk_pallas
 
@@ -106,3 +113,53 @@ def test_sharded_slab_topk_compiles_for_four_chips(topo):
         jax.ShapeDtypeStruct((16, D), jnp.float32, sharding=rep),
         jax.ShapeDtypeStruct((16, N), jnp.int32, sharding=cols)).compile()
     assert "all-gather" in compiled.as_text()
+
+
+def _computation(text: str, name: str) -> str:
+    start = text.index(f"\n%{name} ")
+    return text[start:text.index("\n}\n", start)]
+
+
+def _results(text: str, elements: int):
+    """(name, opcode) of each instruction whose float32 result holds
+    ``elements`` numbers."""
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = f32\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if m and math.prod(int(d) for d in m.group(2).split(",") if d) \
+                == elements:
+            out.append((m.group(1), m.group(3)))
+    return out
+
+
+def test_decode_step_reads_the_kv_cache_in_place(one_chip):
+    """stablelm-2-1.6b's decode step (two of its layers; a cache of 2,048
+    prompt and 16 answer positions, float32): the layer loop's body holds
+    no instruction whose result is a whole layer's K or V (a slice, copy,
+    transpose or update of it), and no copy of the stacked cache surrounds
+    the loop.  Each layer is read where it lies, and the step's rows go
+    into the stack in place."""
+    from repro.models import decode_step, init_cache, init_params
+    cfg = dataclasses.replace(configs.get_config("stablelm-1.6b"),
+                              num_layers=2)
+    positions = 2048 + 16
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    caches = jax.eval_shape(lambda: init_cache(cfg, 1, positions))
+    text = jax.jit(lambda p, t, c, n: decode_step(p, cfg, t, c, n),
+                   donate_argnums=2).lower(
+        jax.tree.map(on_chip, params),
+        on_chip(jax.ShapeDtypeStruct((1, 1), jnp.int32)),
+        jax.tree.map(on_chip, caches),
+        on_chip(jax.ShapeDtypeStruct((), jnp.int32))).compile().as_text()
+    layer = positions * cfg.num_kv_heads * cfg.head_dim
+    body = re.search(r"while\(.*?body=%([\w.\-]+)", text).group(1)
+    assert _results(_computation(text, body), layer) == []
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    stack = [op for _, op in _results(entry, cfg.num_layers * layer)]
+    assert "copy" not in stack and "transpose" not in stack
+    assert stack.count("dynamic-update-slice") == 2, stack
